@@ -91,12 +91,23 @@ sheep_banner() {
   return 0
 }
 
-# Launch graph2tree on the mesh path.  With SHEEP_PROCS > 1 this is the
+# Launch graph2tree on the mesh path.  By default ONE process runs the
+# SPMD program over all local devices.  With SHEEP_PROCS > 1 this is the
 # mpiexec analog: that many processes join one jax.distributed mesh via
-# the SHEEP_COORDINATOR contract (process 0 owns all prints and writes);
-# otherwise a single process runs the SPMD program over its local devices.
+# the SHEEP_COORDINATOR contract (process 0 owns all prints and writes).
+# All of them run on this host, and a process that reaches the TPU claims
+# every local chip, so the second would fail or hang: SHEEP_PROCS > 1 is
+# refused unless JAX_PLATFORMS=cpu (the host-device emulation the tests
+# use).
 sheep_mesh_graph2tree() {
   local procs="${SHEEP_PROCS:-1}"
+  if [ "$procs" -gt 1 ] && [ "${JAX_PLATFORMS:-}" != "cpu" ]; then
+    echo "SHEEP_PROCS=$procs would start $procs graph2tree processes on" \
+         "this host, and each would claim every local chip; unset" \
+         "SHEEP_PROCS to run one process over all local chips (or set" \
+         "JAX_PLATFORMS=cpu for the host-device emulation)" >&2
+    return 2
+  fi
   if [ "$procs" -gt 1 ]; then
     local port p pids='' rc=0
     # an OS-assigned free port, not a blind pick from the ephemeral range

@@ -66,8 +66,8 @@ def main() -> None:
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    from sheep_tpu.cli.common import ensure_jax_platform
-    ensure_jax_platform()
+    from sheep_tpu.cli.common import init_compile_cache
+    init_compile_cache()
     import jax
 
     platform = jax.devices()[0].platform
@@ -77,13 +77,13 @@ def main() -> None:
     from sheep_tpu.parallel.chunked import (build_links_chunked_sharded,
                                             stage_edges_2d)
     from sheep_tpu.parallel.mesh import make_mesh
-    from scripts.tpu_diag import edges  # cached R-MAT
+    from sheep_tpu.utils import rmat_edges
 
     from sheep_tpu.utils.envinfo import env_capture
 
     n = 1 << log_n
     e = factor << log_n
-    tail, head = edges(log_n, factor)
+    tail, head = rmat_edges(log_n, e, seed=1)
     rec = {"log_n": log_n, "edges": e, "platform": platform,
            "devices": ndev, "reps": reps, "env": env_capture(platform),
            "curve": []}

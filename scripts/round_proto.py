@@ -22,13 +22,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from tpu_diag import edges as load  # shared R-MAT cache (same seed/path)
+from sheep_tpu.utils import rmat_edges
 
 
 def links_of(log_n):
     from sheep_tpu.core.sequence import degree_sequence, sequence_positions
-    tail, head = load(log_n)
+    tail, head = rmat_edges(log_n, 8 << log_n, seed=1)
     n = 1 << log_n
     seq = degree_sequence(tail, head)
     pos = sequence_positions(seq, n - 1).astype(np.int64)

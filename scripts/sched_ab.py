@@ -27,7 +27,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from scripts.tpu_diag import edges
+from sheep_tpu.utils import rmat_edges
 
 
 def main() -> None:
@@ -35,14 +35,14 @@ def main() -> None:
     reps = int(sys.argv[2]) if len(sys.argv) > 2 else 2
     n = 1 << log_n
 
-    from sheep_tpu.cli.common import ensure_jax_platform
-    ensure_jax_platform()
+    from sheep_tpu.cli.common import init_compile_cache
+    init_compile_cache()
     import jax
     import jax.numpy as jnp
     from sheep_tpu.ops.build import prepare_links
     from sheep_tpu.ops import forest as F
 
-    tail, head = edges(log_n)
+    tail, head = rmat_edges(log_n, 8 << log_n, seed=1)
     t = jax.device_put(jnp.asarray(tail, jnp.int32))
     h = jax.device_put(jnp.asarray(head, jnp.int32))
     _, _, _, lo0, hi0, _ = prepare_links(t, h, n)

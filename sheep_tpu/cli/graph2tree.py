@@ -170,8 +170,8 @@ def main(argv: list[str] | None = None) -> int:
         # the reference's per-rank partial writes, every process here
         # computes the full (replicated) result, so non-leaders skip
         # writes entirely rather than racing on the same files.
-        from .common import ensure_jax_platform, maybe_init_distributed
-        ensure_jax_platform()
+        from .common import init_compile_cache, maybe_init_distributed
+        init_compile_cache()
         if maybe_init_distributed() != 0:
             is_leader = False
             proc0 = False
@@ -269,8 +269,6 @@ def main(argv: list[str] | None = None) -> int:
         # SURVEY §7); with -i/-r they run as the single-worker equivalent:
         # device degree sort, then the host parameterized insert — matching
         # a 1-rank MPI run of the reference with the same jopts.
-        from .common import ensure_jax_platform
-        ensure_jax_platform()
         from ..core.jxn import build_forest_jxn
         from ..ops.sort import degree_sequence_device
         if not use_mesh_sort and sequence_filename:
@@ -289,8 +287,6 @@ def main(argv: list[str] | None = None) -> int:
                 print_phase("Reduced", clock.phase_seconds())
     elif use_mesh:
         # Fused SPMD program over the device mesh: sort + map [+ reduce].
-        from .common import ensure_jax_platform
-        ensure_jax_platform()
         import jax
 
         from ..parallel.build import build_graph_distributed
@@ -430,8 +426,8 @@ def main(argv: list[str] | None = None) -> int:
             # (no mesh rung — the user did not ask for -i/-r).
             import dataclasses
 
-            from .common import ensure_jax_platform
-            ensure_jax_platform()
+            from .common import init_compile_cache
+            init_compile_cache()
             from ..runtime.driver import build_graph_resilient
             serial_cfg = dataclasses.replace(
                 rt_cfg, ladder=("single", "host"))

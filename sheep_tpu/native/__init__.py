@@ -1,14 +1,16 @@
-"""ctypes bindings to the C++ host runtime (``libsheep_native.so``).
+"""ctypes bindings to the C++ host runtime (``libsheep_native-<hash>.so``).
 
 The library is built on demand with ``make`` (g++ is part of the toolchain;
 pybind11 is not, so the ABI is plain C over caller-allocated numpy buffers).
-If the toolchain is unavailable the callers fall back to the numpy oracle —
-``available()`` reports which path is live.
+Its file name carries a hash of the source and the Makefile, so a stale or
+foreign build is never loaded.  If the toolchain is unavailable the callers
+fall back to the numpy oracle — ``available()`` reports which path is live.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +20,6 @@ import numpy as np
 from ..obs import trace as _obs
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libsheep_native.so")
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
@@ -30,37 +31,52 @@ _u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
+def so_path(native_dir: str = _DIR) -> str:
+    """The library built from ``native_dir``'s current source: its name
+    is keyed on a hash of ``src/sheep_native.cpp`` and the ``Makefile``."""
+    h = hashlib.sha256()
+    for rel in ("Makefile", os.path.join("src", "sheep_native.cpp")):
+        with open(os.path.join(native_dir, rel), "rb") as f:
+            h.update(f.read())
+    return os.path.join(native_dir,
+                        f"libsheep_native-{h.hexdigest()[:16]}.so")
+
+
+def ensure_built(native_dir: str = _DIR) -> str:
+    """Build the library for ``native_dir``'s source unless that exact
+    build exists; returns its path.  Raises OSError or
+    CalledProcessError when the toolchain fails."""
+    so = so_path(native_dir)
+    if not os.path.exists(so):
+        # Concurrent CLI processes may race to build: compile to a
+        # process-unique name and publish with an atomic rename.
+        tmp = f"{so}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["make", "-C", native_dir, f"OUT={os.path.basename(tmp)}"],
+                check=True,
+                stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+            os.replace(tmp, so)
+        finally:
+            try:
+                os.unlink(tmp)
+            except OSError:
+                pass
+    return so
+
+
 def _load() -> ctypes.CDLL | None:
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
         _tried = True
-        src = os.path.join(_DIR, "src", "sheep_native.cpp")
-        stale = (os.path.exists(src) and os.path.exists(_SO)
-                 and os.path.getmtime(_SO) < os.path.getmtime(src))
-        if not os.path.exists(_SO) or stale:
-            # Concurrent CLI processes may race to build: compile to a
-            # process-unique name and publish with an atomic rename.
-            tmp = f"{_SO}.{os.getpid()}.tmp"
-            try:
-                subprocess.run(
-                    ["make", "-C", _DIR, f"OUT={os.path.basename(tmp)}"],
-                    check=True,
-                    stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-                os.replace(tmp, _SO)
-            except (OSError, subprocess.CalledProcessError):
-                try:
-                    os.unlink(tmp)
-                except OSError:
-                    pass
-                return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(ensure_built())
             _bind(lib)
-        except (OSError, AttributeError):
-            # missing file, wrong arch, or a stale .so lacking a newer
-            # symbol — fall back to the numpy oracle
+        except (OSError, subprocess.CalledProcessError, AttributeError):
+            # no toolchain, wrong arch, or a library lacking a symbol —
+            # fall back to the numpy oracle
             return None
         _lib = lib
         return _lib

@@ -61,9 +61,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..obs import trace as _obs
 from ..ops.forest import (_CHUNK_SCHEDULE as _SCHEDULE, _depth_tier,
                           _lift_descend, _rewrite_sorted, pst_weights,
                           sort_links)
@@ -86,8 +87,7 @@ def _row_round(lo, hi, n: int, levels: int, f_combine):
     live = jnp.sum(lo != sent, dtype=jnp.int32)
     lo, hi, rewrites = _rewrite_sorted(lo, hi, n)
     # one-step min-up table, combined across the mesh BEFORE lifting so
-    # every worker lifts the same (global, for reduce rounds) f; the
-    # shared descent carries the Pallas fast-path gate
+    # every worker lifts the same (global, for reduce rounds) f
     f = jnp.full(n + 1, sent, jnp.int32).at[lo].min(hi)
     f = f_combine(f)
     lo, jumped = _lift_descend(lo, hi, n, levels, f)
@@ -541,7 +541,6 @@ def reduce_links_sharded(lo, hi, n: int, mesh, global_f: bool,
         moved_i, live_i = (int(x) for x in fetch(stats))  # one sync
         # flight recorder: the mesh loop's per-chunk record (same shape
         # as the hosted loop's "reduce.chunk" — one rollup code path)
-        from ..obs import trace as _obs
         _obs.event("reduce.chunk", live=live_i, moved=moved_i,
                    rounds=rounds, mesh=True)
         if moved_i == 0:
@@ -803,6 +802,9 @@ def _stage_inputs(tail, head, num_vertices, num_workers, seq):
     if n == 0:
         return mesh, 0, None, None, None
     t2d, h2d = stage_edges_2d(tail, head, n, mesh)
+    # where the edge shards really sit (not all on device 0)
+    _obs.event("mesh.stage", workers=mesh.size,
+               devices=len(t2d.sharding.device_set))
     pos_d = None
     if seq is not None:
         from ..core.sequence import sequence_positions
@@ -866,8 +868,11 @@ def build_graph_chunked_distributed(tail, head, num_vertices=None,
     if n == 0:
         return (np.empty(0, np.uint32),
                 Forest(np.empty(0, np.uint32), np.empty(0, np.uint32)))
+    if timings is None:
+        timings = {}
     dseq, _, m, parent, pst = build_links_chunked_sharded(
         t2d, h2d, n, mesh, pos=pos_d, fetch=_fetch, timings=timings)
+    _obs.event("mesh.build", **timings)
     if seq is None:
         m = int(_fetch(m))
         out_seq = _fetch(dseq)[:m].astype(np.uint32)

@@ -56,8 +56,6 @@ def init_distributed(coordinator_address: str | None = None,
     try:
         jax.distributed.initialize(
             initialization_timeout=int(connect_timeout_s), **kw)
-    except TypeError:  # pragma: no cover - very old jax: no timeout knob
-        jax.distributed.initialize(**kw)
     except Exception as exc:
         addr = coordinator_address or \
             os.environ.get("JAX_COORDINATOR_ADDRESS", "<auto>")

@@ -127,9 +127,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _K("SHEEP_SORT_PACK64", "str", "",
        "mesh", "pack64 device sort arm: 1 forces, 0 disables, unset "
        "auto"),
-    _K("SHEEP_PALLAS", "str", "",
-       "mesh", "pallas jump-table kernel: 1 on-device, 'interpret' "
-       "interpreter mode, unset off"),
     _K("SHEEP_ICI_GBPS", "float", "",
        "mesh", "assumed per-link ICI bandwidth for bench modeling"),
     # -- streaming handoff / hybrid tail (ISSUE 8) -------------------------
@@ -396,13 +393,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "bench", "edges per vertex of the synthetic bench graphs"),
     _K("SHEEP_BENCH_TIMEOUT", "float", "",
        "bench", "per-arm bench timeout"),
-    _K("SHEEP_BENCH_STARTUP_TIMEOUT", "float", "",
-       "bench", "bench subprocess startup timeout"),
-    _K("SHEEP_BENCH_NO_FALLBACK", "flag", "0",
-       "bench", "fail instead of falling back to cpu when the backend "
-       "is sick"),
-    _K("SHEEP_BENCH_NO_PROBE", "flag", "0",
-       "bench", "skip the backend probe before benching"),
     _K("SHEEP_BENCH_THREADS_AB", "flag", "0",
        "bench", "per-size forced-thread A/B arm in bench.py"),
     _K("SHEEP_MESHBENCH_REPS", "int", "3",
@@ -417,12 +407,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "bench", "skip the in-RAM oracle arm of scale_run"),
     _K("SHEEP_REFSCALE_STREAM", "flag", "0",
        "bench", "reference_scale_run streamed arm"),
-    _K("SHEEP_WATCH_INTERVAL", "float", "",
-       "bench", "tpu_watcher poll interval"),
-    _K("SHEEP_WATCH_MAX_HOURS", "float", "",
-       "bench", "tpu_watcher give-up horizon"),
-    _K("SHEEP_WATCH_PROBE_TIMEOUT", "float", "",
-       "bench", "tpu_watcher probe timeout"),
     # -- shell drivers (scripts/*.sh) --------------------------------------
     _K("SHEEP_BIN", "path", "bin/",
        "shell", "where the shell drivers find the sheep binaries"),

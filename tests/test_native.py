@@ -270,3 +270,26 @@ def test_pre_weights_native_python_oracle_agree(seed):
         pre_weights(tail, head, seq, impl="python"), ref)
     np.testing.assert_array_equal(
         pre_weights(tail, head, seq, impl="native"), ref)
+
+
+def test_library_rebuilds_when_source_hash_changes(tmp_path):
+    """The loaded library is keyed on a hash of its source and Makefile:
+    an edit yields a new name that is built fresh, and a stale or foreign
+    ``libsheep_native.so`` beside it is never the one loaded."""
+    import ctypes
+    import os
+    import shutil
+
+    src = os.path.dirname(native.__file__)
+    shutil.copy(os.path.join(src, "Makefile"), tmp_path / "Makefile")
+    shutil.copytree(os.path.join(src, "src"), tmp_path / "src")
+    (tmp_path / "libsheep_native.so").write_bytes(b"not a library")
+    first = native.ensure_built(str(tmp_path))
+    assert first == native.so_path(str(tmp_path))
+    assert os.path.basename(first) != "libsheep_native.so"
+    with open(tmp_path / "src" / "sheep_native.cpp", "a") as f:
+        f.write("\n// edited\n")
+    second = native.so_path(str(tmp_path))
+    assert second != first and not os.path.exists(second)
+    assert native.ensure_built(str(tmp_path)) == second
+    native._bind(ctypes.CDLL(second))  # a complete, loadable library

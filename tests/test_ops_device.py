@@ -256,15 +256,18 @@ def test_build_graph_hybrid_given_seq(with_host_edges, handoff):
 
 @pytest.mark.parametrize("given", [False, True])
 @pytest.mark.parametrize("handoff", [2, 1000])
-def test_build_graph_hybrid_prefetch_failure_lazy_pst(monkeypatch, handoff,
-                                                      given):
+def test_build_graph_hybrid_prefetch_failure_raises(monkeypatch, handoff,
+                                                    given):
     # with host_edges the device skips its pst scatter (with_pst=False) in
     # both the degree-sort and given-seq branches; if the host prefetch
-    # then dies, the fallback must materialize pst lazily on device and
-    # still be bit-identical to the oracle
+    # then dies, its error must reach the caller (no silent slower path);
+    # where no prefetch runs, the result is bit-identical to the oracle
     import sheep_tpu.ops.build as build_mod
 
+    calls = []
+
     def boom(*a, **k):
+        calls.append(1)
         raise RuntimeError("prefetch failure injected by test")
 
     monkeypatch.setattr(build_mod, "_host_seq_pst", boom)
@@ -277,9 +280,14 @@ def test_build_graph_hybrid_prefetch_failure_lazy_pst(monkeypatch, handoff,
     want_seq = seq_in if given else full
     want = build_forest(tail, head, want_seq,
                         max_vid=int(max(tail.max(), head.max())))
-    seq, forest = build_mod.build_graph_hybrid(
-        tail, head, handoff_factor=handoff, host_edges=(tail, head),
-        seq=seq_in)
+    try:
+        seq, forest = build_mod.build_graph_hybrid(
+            tail, head, handoff_factor=handoff, host_edges=(tail, head),
+            seq=seq_in)
+    except RuntimeError as exc:
+        assert calls and "prefetch failure injected" in str(exc)
+        return
+    assert not calls, "a failed prefetch was swallowed"
     np.testing.assert_array_equal(seq, want_seq)
     np.testing.assert_array_equal(forest.parent, want.parent)
     np.testing.assert_array_equal(forest.pst_weight, want.pst_weight)

@@ -212,6 +212,7 @@ def test_spec_handoff_restart_policy():
             self._remaining = remaining
             self.done_slices = done
             self.failed = False
+            self.error = None
         def finished(self):
             return self._remaining == 0
         def remaining_bytes(self):
@@ -294,6 +295,7 @@ def test_spec_wait_timeout_falls_back_serial(overlap_env):
 
     class WedgedFetcher:
         failed = False
+        error = None
         done_slices = 1
         def finished(self):
             return False
@@ -333,6 +335,7 @@ def test_abort_slow_stream_does_not_poison(overlap_env):
 
     class SlowFetcher:
         failed = False
+        error = None
         done_slices = 2
         def join(self, timeout=None, mark_failed=True):
             return True  # still draining, but abort passes mark_failed=False
@@ -344,16 +347,30 @@ def test_abort_slow_stream_does_not_poison(overlap_env):
             return (np.zeros(100, np.int32), np.ones(100, np.int32))
 
     sp.active = SlowFetcher()
-    sp._abandon()
-    assert sp.dead is False, "slow abort must not disable speculation"
+    sp._abandon()  # a slow abort is not an error: nothing raises
     assert len(sp.kept) == 1, "landed partial slices must be kept"
+
+
+def test_spec_fetch_error_reaches_the_caller(overlap_env):
+    """A fetch thread that died on a device-runtime error must not be
+    abandoned in silence: the next chunk hook re-raises its exception."""
+    from sheep_tpu.ops.build import _SpecHandoff
+
+    sp = _SpecHandoff(1 << 16)
+
+    class DeadFetcher:
+        failed = True
+        error = RuntimeError("injected fetch fault")
+
+    sp.active = DeadFetcher()
+    with pytest.raises(RuntimeError, match="injected fetch fault"):
+        sp.on_chunk(None, None, 1000)
 
 
 def test_hybrid_overlap_pair_mode_large_n(overlap_env):
     """End-to-end hybrid at n >= 2^24 (sparse edges over a huge vertex
     space): the overlapped stream must take the int32-pair mode (no
-    6-byte packing above 2^24) and stay oracle-exact — the shape the
-    watcher's 2^24 on-chip step runs."""
+    6-byte packing above 2^24) and stay oracle-exact."""
     n = (1 << 24) + 1000
     e = 60_000
     rng = np.random.default_rng(98)

@@ -168,3 +168,23 @@ def test_heartbeat_loop_dies_with_shell(tmp_path):
     time.sleep(0.4)
     assert os.path.getmtime(hb) == m1, \
         "orphaned heartbeat loop kept beating after its worker died"
+
+
+# ---------------------------------------------------------------------------
+# sheep_mesh_graph2tree: one process per chip
+# ---------------------------------------------------------------------------
+
+
+def test_mesh_multiprocess_refused_off_cpu(tmp_path):
+    """SHEEP_PROCS > 1 starts its processes on this host; off the cpu
+    emulation each would claim every local chip, so the launcher refuses
+    with a message and starts nothing."""
+    marker = tmp_path / "started"
+    stub = tmp_path / "graph2tree"
+    stub.write_text(f"#!/bin/sh\ntouch {marker}\n")
+    stub.chmod(0o755)
+    proc = bash(f'SHEEP_BIN={tmp_path} sheep_mesh_graph2tree x',
+                env_extra={"SHEEP_PROCS": "2", "JAX_PLATFORMS": "tpu"})
+    assert proc.returncode == 2
+    assert "claim every local chip" in proc.stderr
+    assert not marker.exists()

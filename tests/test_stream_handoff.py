@@ -90,15 +90,12 @@ def test_device_window_queue_forced_on_cpu(stream_env, packed):
     np.testing.assert_array_equal(f.pst_weight, f0.pst_weight)
 
 
-def test_mid_stream_fetch_failure_falls_back_serial(stream_env,
-                                                    monkeypatch):
-    """A slice fetch dying mid-stream must degrade to the serial fetch
-    of the still-alive device arrays — bit-identical result, honest
-    stream_mode."""
+def test_mid_stream_fetch_failure_raises(stream_env, monkeypatch):
+    """A slice fetch dying mid-stream is a device-runtime error: it must
+    reach the caller, not be swallowed by a slower host path."""
     import sheep_tpu.ops.build as B
     from sheep_tpu.ops import build_graph_hybrid
     n, tail, head = _graph()
-    seq0, f0 = _serial_reference(tail, head, n, stream_env)
     stream_env.setenv("SHEEP_STREAM_DEVICE_WINDOWS", "1")
     stream_env.setenv("SHEEP_HANDOFF_WINDOWS", "4")
     stream_env.setenv("SHEEP_OVERLAP_SLICE", "4096")
@@ -112,21 +109,17 @@ def test_mid_stream_fetch_failure_falls_back_serial(stream_env,
         return real(buf, start, length)
 
     monkeypatch.setattr(B, "_slice_rows", flaky)
-    perf = {}
-    seq, f = build_graph_hybrid(tail, head, n, perf=perf)
-    assert str(perf.get("stream_mode", "")).startswith("fallback:"), perf
-    np.testing.assert_array_equal(seq, seq0)
-    np.testing.assert_array_equal(f.parent, f0.parent)
-    np.testing.assert_array_equal(f.pst_weight, f0.pst_weight)
+    with pytest.raises(RuntimeError, match="injected slice fault"):
+        build_graph_hybrid(tail, head, n)
+    assert calls["n"] == 3
 
 
-def test_mid_fold_failure_falls_back_serial(stream_env, monkeypatch):
-    """The host-side branch too: a fold block raising mid-window falls
-    back cleanly to the serial fetch + monolithic fold."""
+def test_mid_fold_failure_raises(stream_env, monkeypatch):
+    """The host-side branch too: a fold block raising mid-window reaches
+    the caller."""
     import sheep_tpu.core.forest as cf
     from sheep_tpu.ops import build_graph_hybrid
     n, tail, head = _graph()
-    seq0, f0 = _serial_reference(tail, head, n, stream_env)
     stream_env.setenv("SHEEP_HANDOFF_WINDOWS", "4")
     real = cf.links_fold
     calls = {"n": 0}
@@ -145,12 +138,8 @@ def test_mid_fold_failure_falls_back_serial(stream_env, monkeypatch):
         return fold
 
     monkeypatch.setattr(cf, "links_fold", flaky_fold)
-    perf = {}
-    seq, f = build_graph_hybrid(tail, head, n, perf=perf)
-    assert str(perf.get("stream_mode", "")).startswith("fallback:"), perf
-    np.testing.assert_array_equal(seq, seq0)
-    np.testing.assert_array_equal(f.parent, f0.parent)
-    np.testing.assert_array_equal(f.pst_weight, f0.pst_weight)
+    with pytest.raises(RuntimeError, match="injected fold fault"):
+        build_graph_hybrid(tail, head, n)
 
 
 def test_host_seq_arm_parity(stream_env):

@@ -1,0 +1,10 @@
+"""Seconds per partition_tree call in the in-memory evaluator's
+ECV(down) and ECV(up) step, with their balances: the flight
+recorder's ``evaluate.ecv_seq`` spans
+(partition/evaluate.py), summed per call."""
+
+from spanread import per_call, span_sum
+
+
+def read(obs):
+    return per_call(obs.partition_records, span_sum("evaluate.ecv_seq"))

@@ -147,13 +147,16 @@ def run_pipeline(graph: str, work: str, workers: int) -> dict:
     t2 = time.perf_counter()
     records, _, _ = read_trace(trace_f)
     events: dict[str, list[dict]] = {}
+    spans: dict[str, float] = {}
     for r in records:
         if r.get("k") == "ev":
             events.setdefault(r["name"], []).append(r.get("a", {}))
+        elif r.get("k") == "span":
+            spans[r["name"]] = spans.get(r["name"], 0.0) + r["dur"]
     parent, pst = read_tree(tre_f)
     return {"seq": read_sequence(seq_f), "parent": parent, "pst": pst,
             "faqs": _parse_faqs(build_out), **_parse_partition(part_out),
-            "events": events,
+            "events": events, "spans": spans,
             "graph2tree_s": t1 - t0, "partition_tree_s": t2 - t1}
 
 
@@ -200,6 +203,7 @@ def _report_build(got: dict, workers: int) -> None:
     """Print what the build reports about its device path, and fail on a
     fallback or on edge shards that did not spread over the mesh."""
     ev = got["events"]
+    spans = got.get("spans", {})
     if workers == 1:
         hand = ev.get("hybrid.handoff")
         if not hand:
@@ -209,8 +213,9 @@ def _report_build(got: dict, workers: int) -> None:
         _say(f"  device reduce rounds: {h.get('rounds')}, live links at "
              f"handoff: {h.get('live')} (folded {h.get('handoff_links')}), "
              f"stream_mode: {h.get('stream_mode')}, kind: {h.get('kind')}; "
-             f"reduce loop {h.get('loop_s')}s, handoff tail "
-             f"{h.get('fetch_tail_s')}s (host clock)")
+             f"reduce loop {spans.get('reduce.loop')}s, handoff fetch "
+             f"{spans.get('handoff.fetch')}s, fold "
+             f"{spans.get('handoff.fold')}s (host clock)")
         # a handoff that reached the native fold took the streamed
         # windowed tail; any other mode is a path the chip should not take
         if h.get("kind") == "forest" and h.get("stream_mode") != "windowed":

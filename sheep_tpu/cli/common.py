@@ -12,6 +12,8 @@ import time
 
 import numpy as np
 
+from ..obs import trace as obs
+
 
 def maybe_start_heartbeat():
     """Start the worker heartbeat when a supervisor launched this process
@@ -143,8 +145,9 @@ def graph_stats(edges) -> tuple[int, int]:
     """(nodes, edges) as the reference reports them: nodes = vertices with
     nonzero degree (graph_wrapper.h:75-77), edges = file records
     (max_edges/2 of the undirected-doubled graph, :79-81)."""
-    deg = edges.degrees()
-    return int((deg > 0).sum()), edges.num_edges
+    with obs.span("cli.stats", records=edges.num_edges):
+        deg = edges.degrees()
+        return int((deg > 0).sum()), edges.num_edges
 
 
 def print_tree(seq: np.ndarray, parent: np.ndarray, pst: np.ndarray) -> None:

@@ -31,6 +31,7 @@ from ..core.validate import is_valid_forest
 from ..io.edges import load_edges
 from ..io.seqfile import read_sequence, write_sequence
 from ..io.trefile import write_tree
+from ..obs import trace as obs
 from ..partition.partition import Partition
 from .common import PhaseClock, graph_stats, print_phase, print_tree
 
@@ -73,6 +74,14 @@ def _finish_sort(seq, use_mesh_sort, sequence_filename, clock,
 
 
 def main(argv: list[str] | None = None) -> int:
+    """The command; one ``cli.graph2tree`` span covers the call, and the
+    layers' spans (io.load, prep, reduce.loop, handoff.*, io.write, ...)
+    are its direct children."""
+    with obs.span("cli.graph2tree"):
+        return _main(argv)
+
+
+def _main(argv: list[str] | None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     from .common import maybe_start_heartbeat
     _hb = maybe_start_heartbeat()  # noqa: F841 — beats while we build

@@ -20,6 +20,7 @@ from ..core.sequence import degree_sequence
 from ..io.edges import load_edges
 from ..io.seqfile import read_sequence
 from ..io.trefile import read_tree
+from ..obs import trace as obs
 from ..partition.evaluate import evaluate_partition
 from ..partition.partition import Partition
 from ..partition.tree_partition import TreePartitionOptions
@@ -71,9 +72,11 @@ def _evaluate_streamed(graph_filename, sequence_filename, forest, popts,
     # One cheap streaming pass for the vid space + record count.
     mx = len(seq) and int(seq.max())
     file_edges = 0
-    for t, h in iter_dat_blocks(graph_filename, block_edges):
-        file_edges += len(t)
-        mx = max(mx, int(t.max(initial=0)), int(h.max(initial=0)))
+    with obs.span("io.load", streamed=True) as sp:
+        for t, h in iter_dat_blocks(graph_filename, block_edges):
+            file_edges += len(t)
+            mx = max(mx, int(t.max(initial=0)), int(h.max(initial=0)))
+        sp.annotate(records=file_edges, bytes=12 * file_edges)
     pos = sequence_positions(seq, mx).astype(np.int64)
     factory = lambda: iter_dat_blocks(graph_filename, block_edges)
     for parts_arg in parts_args:
@@ -89,6 +92,14 @@ def _evaluate_streamed(graph_filename, sequence_filename, forest, popts,
 
 
 def main(argv: list[str] | None = None) -> int:
+    """The command; one ``cli.partition_tree`` span covers the call, and
+    the layers' spans (io.read, io.load, partition.tree,
+    partition.evaluate) are its direct children."""
+    with obs.span("cli.partition_tree"):
+        return _main(argv)
+
+
+def _main(argv: list[str] | None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     try:
         opts, args = getopt.gnu_getopt(argv, "vfb:xdug:o:")

@@ -39,6 +39,7 @@ import numpy as np
 from ..integrity.errors import MalformedArtifact
 from ..integrity.sidecar import (checksummed_write, read_sidecar,
                                  resolve_policy, verify_bytes)
+from ..obs import trace as obs
 
 _XS1_DTYPE = np.dtype(
     [("tail", "<u4"), ("head", "<u4"), ("weight", "<f4")]
@@ -233,12 +234,14 @@ def load_edges(path: str, part: int = 0, num_parts: int = 0,
     same effect without recompiling (the reference needs a rebuild).
     ``integrity``: strict/repair/trust (default: env SHEEP_INTEGRITY).
     """
-    if path.endswith(".dat"):
-        el = read_dat(path, part, num_parts, integrity=integrity)
-    else:
-        el = read_net(path, part, num_parts, integrity=integrity)
-    if dedup or os.environ.get("SHEEP_DDUP_GRAPH", "") == "1":
-        el = dedup_edges(el)
+    with obs.span("io.load") as sp:
+        if path.endswith(".dat"):
+            el = read_dat(path, part, num_parts, integrity=integrity)
+        else:
+            el = read_net(path, part, num_parts, integrity=integrity)
+        if dedup or os.environ.get("SHEEP_DDUP_GRAPH", "") == "1":
+            el = dedup_edges(el)
+        sp.annotate(records=el.num_edges, bytes=os.path.getsize(path))
     return el
 
 

@@ -20,6 +20,7 @@ import numpy as np
 
 from ..integrity.errors import MalformedArtifact
 from ..integrity.sidecar import checksummed_write, resolve_policy, verify_bytes
+from ..obs import trace as obs
 
 #: the only bytes a well-formed TEXT sequence may contain
 _TEXT_BYTES = frozenset(b"0123456789 \t\r\n")
@@ -32,17 +33,22 @@ def write_sequence(seq: np.ndarray, path: str, binary: bool = False) -> None:
     # a refusal is a typed DiskExhausted before any bytes land (text rows
     # are priced at the uint32 ceiling of 11 bytes/line).
     seq = np.asarray(seq, dtype=np.uint32)
-    if binary:
-        with checksummed_write(path, "wb",
-                               expect_bytes=8 + 4 * len(seq)) as f:
-            f.write(np.uint64(len(seq)).tobytes())
-            f.write(seq.astype("<u4").tobytes())
-    else:
-        with checksummed_write(path, "w",
-                               expect_bytes=11 * len(seq)) as f:
-            f.write("\n".join(map(str, seq.tolist())))
-            if len(seq):
-                f.write("\n")
+    with obs.span("io.write", kind="seq") as sp:
+        if binary:
+            with checksummed_write(path, "wb",
+                                   expect_bytes=8 + 4 * len(seq)) as f:
+                f.write(np.uint64(len(seq)).tobytes())
+                f.write(seq.astype("<u4").tobytes())
+            nbytes = 8 + 4 * len(seq)
+        else:
+            text = "\n".join(map(str, seq.tolist()))
+            with checksummed_write(path, "w",
+                                   expect_bytes=11 * len(seq)) as f:
+                f.write(text)
+                if len(seq):
+                    f.write("\n")
+            nbytes = len(text) + (1 if len(seq) else 0)
+        sp.annotate(bytes=nbytes)
 
 
 def _looks_text(data: bytes) -> bool:
@@ -64,15 +70,16 @@ def read_sequence(path: str, binary: bool | str = False,
     """Read an elimination order.  ``binary``: False (text), True, or
     "auto" to sniff the on-disk format (the fsck path)."""
     mode = resolve_policy(integrity)
-    with open(path, "rb") as f:
-        data = f.read()
-    verify_bytes(path, data, mode)
-    if binary == "auto":
-        binary = not _looks_text(data) or (_binary_consistent(data)
-                                           and len(data) >= 8)
-    if binary:
-        return _parse_binary(path, data, mode)
-    return _parse_text(path, data, mode)
+    with obs.span("io.read", kind="seq"):
+        with open(path, "rb") as f:
+            data = f.read()
+        verify_bytes(path, data, mode)
+        if binary == "auto":
+            binary = not _looks_text(data) or (_binary_consistent(data)
+                                               and len(data) >= 8)
+        if binary:
+            return _parse_binary(path, data, mode)
+        return _parse_text(path, data, mode)
 
 
 def _parse_binary(path: str, data: bytes, mode: str) -> np.ndarray:

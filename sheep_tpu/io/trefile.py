@@ -24,6 +24,7 @@ import numpy as np
 from .. import INVALID_JNID
 from ..integrity.errors import MalformedArtifact
 from ..integrity.sidecar import checksummed_write, resolve_policy, verify_bytes
+from ..obs import trace as obs
 
 _NODE_DTYPE = np.dtype([("parent", "<u4"), ("pst_weight", "<u4")])
 
@@ -31,18 +32,21 @@ _NODE_DTYPE = np.dtype([("parent", "<u4"), ("pst_weight", "<u4")])
 def write_tree(path: str, parent: np.ndarray, pst_weight: np.ndarray,
                sig: str | None = None) -> None:
     assert len(parent) == len(pst_weight)
-    rec = np.empty(len(parent), dtype=_NODE_DTYPE)
-    rec["parent"] = parent
-    rec["pst_weight"] = pst_weight
-    # Crash-safe: the shell pipeline polls for .tre files appearing on a
-    # shared filesystem (scripts/lib.sh sheep_wait_for), so a consumer
-    # must never observe a torn header/record prefix from a killed writer.
-    # Exhaustion-aware (ISSUE 5): the exact size preflights the disk.
-    extra = {"sig": sig} if sig else None
-    with checksummed_write(path, "wb", extra=extra,
-                           expect_bytes=4 + rec.nbytes) as f:
-        f.write(np.uint32(len(parent)).tobytes())
-        f.write(rec.tobytes())
+    with obs.span("io.write", kind="tre",
+                  bytes=4 + len(parent) * _NODE_DTYPE.itemsize):
+        rec = np.empty(len(parent), dtype=_NODE_DTYPE)
+        rec["parent"] = parent
+        rec["pst_weight"] = pst_weight
+        # Crash-safe: the shell pipeline polls for .tre files appearing
+        # on a shared filesystem (scripts/lib.sh sheep_wait_for), so a
+        # consumer must never observe a torn header/record prefix from a
+        # killed writer.  Exhaustion-aware: the exact size preflights the
+        # disk.
+        extra = {"sig": sig} if sig else None
+        with checksummed_write(path, "wb", extra=extra,
+                               expect_bytes=4 + rec.nbytes) as f:
+            f.write(np.uint32(len(parent)).tobytes())
+            f.write(rec.tobytes())
 
 
 def read_tree(path: str,
@@ -53,6 +57,12 @@ def read_tree(path: str,
     integrity.sidecar.  Structural corruption raises MalformedArtifact in
     every mode; only the checksum layer and best-effort salvage differ.
     """
+    with obs.span("io.read", kind="tre"):
+        return _read_tree(path, integrity)
+
+
+def _read_tree(path: str,
+               integrity: str | None) -> tuple[np.ndarray, np.ndarray]:
     mode = resolve_policy(integrity)
     with open(path, "rb") as f:
         data = f.read()

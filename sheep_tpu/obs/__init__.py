@@ -28,14 +28,14 @@ text timeline — the precursor of the planner's ``plan --explain``.
 from .metrics import (Counter, Gauge, Histogram, Registry,
                       parse_prometheus, proc_status, relabel,
                       set_process_gauges)
-from .trace import (current_rid, enabled, event, new_rid, overlap_stats,
+from .trace import (bind, current_rid, enabled, event, new_rid, overlap_stats,
                     read_trace, read_trace_chain, repair_trace, rid_scope,
                     rollup, span, timed, trace_segments, trace_summary)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry",
     "parse_prometheus", "proc_status", "relabel", "set_process_gauges",
-    "current_rid", "enabled", "event", "new_rid", "overlap_stats",
+    "bind", "current_rid", "enabled", "event", "new_rid", "overlap_stats",
     "read_trace", "read_trace_chain", "repair_trace", "rid_scope",
     "rollup", "span", "timed", "trace_segments", "trace_summary",
 ]

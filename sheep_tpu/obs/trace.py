@@ -10,19 +10,32 @@ File format: JSON Lines, one record per line, append-only::
 
     {"k":"meta","v":1,"pid":...,"t0":<unix>, "argv":[...]}
     {"k":"span","name":"fold","id":7,"par":3,"tid":2,"t":0.0123,
-     "dur":0.456,"a":{"block":4}}
+     "dur":0.456,"cpu":0.449,"a":{"block":4}}
     {"k":"ev","name":"fault","par":3,"tid":2,"t":0.5,"a":{...}}
 
 ``t`` is seconds since the recorder opened (monotonic clock — a clock
 step mid-run cannot reorder the timeline); spans are written at EXIT (so
 ``dur`` is exact), which means a parent line follows its children —
-readers reconstruct the hierarchy from ``id``/``par``.  Every line is
+readers reconstruct the hierarchy from ``id``/``par``.  ``cpu`` is the
+``time.thread_time()`` seconds of the span's own thread over the span:
+``dur - cpu`` is the time its work waited.  Work the span hands to other
+threads (the native runtime's worker threads, a prefetch thread) is not
+in ``cpu``; a thread started through :func:`bind` records its own spans
+with the span that started it as ``par``.  Every line is
 flushed as it lands, so a kill -9 mid-run leaves a readable prefix plus
 at most one torn trailing line — the same contract as the WAL
 (serve/wal.py): :func:`read_trace` refuses the tear strict, salvages the
 prefix in repair/trust, and refuses mid-file rot in every mode.  A CLEAN
 close seals a ``.sum`` sidecar (integrity/sidecar.py) so ``sheep fsck``
 can vouch for a finished trace byte-for-byte.
+
+One clock with the device trace: while a recorder is open and ``jax`` is
+already imported, every span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler capture
+holds the program's spans on its host plane beside the device ops; and
+each backend compile lands as one ``jax.compile`` event (``fun_name``,
+seconds, whether the persistent cache served it).  This module never
+imports jax itself: the serve tier runs without it.
 
 Thread-safety: span nesting is tracked per thread (threading.local), the
 file write is one lock-guarded append per line.  Processes do not share
@@ -45,6 +58,7 @@ import itertools
 import json
 import os
 import re
+import sys
 import threading
 import time
 import warnings
@@ -62,6 +76,10 @@ TRACE_VERSION = 1
 MAX_MB_ENV = "SHEEP_TRACE_MAX_MB"
 
 _SEG_RE = re.compile(r"\.(\d{4})\.trace$")
+
+#: how long closing a recorder waits for a span still waiting on its
+#: device array (end_when_ready) before it closes without that span
+WAITER_JOIN_S = 30.0
 
 
 def _segment_name(path: str, n: int) -> str:
@@ -169,8 +187,24 @@ class _NoopSpan:
     def annotate(self, **attrs):
         pass
 
+    def end_when_ready(self, array):
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
+
+
+class _ParentRef:
+    """The base of a bound thread's span stack (:func:`bind`): the span
+    that started the thread, on another thread, known by its id alone."""
+
+    __slots__ = ("id",)
+
+    def __init__(self, span_id: int):
+        self.id = span_id
+
+    def annotate(self, **attrs):
+        pass
 
 
 def _json_safe(v):
@@ -194,15 +228,25 @@ def _json_safe(v):
 class _Span:
     """One live span (enabled mode).  Created by TraceRecorder.span."""
 
-    __slots__ = ("rec", "name", "attrs", "id", "par", "t0", "rid")
+    __slots__ = ("rec", "name", "attrs", "id", "par", "tid", "t0", "rid",
+                 "cpu0", "ann", "ready")
 
     def __init__(self, rec: "TraceRecorder", name: str, attrs: dict):
         self.rec = rec
         self.name = name
         self.attrs = attrs
+        self.ready = None
 
     def annotate(self, **attrs) -> None:
         self.attrs.update(attrs)
+
+    def end_when_ready(self, array) -> None:
+        """End the span when ``array`` (a device array: anything with
+        ``block_until_ready``) is ready instead of at ``with`` exit.  A
+        waiter thread observes the readiness, so the traced caller runs
+        on without a sync of its own, as it does untraced.  Wait on an
+        array no later program donates."""
+        self.ready = array
 
     def __enter__(self) -> "_Span":
         rec = self.rec
@@ -212,13 +256,26 @@ class _Span:
             stack = tl.stack = []
         self.par = stack[-1].id if stack else None
         self.id = rec._next_id()
+        # the opening thread, kept where a waiter thread ends the span
+        self.tid = threading.get_ident() & 0xFFFF
         self.rid = current_rid()
         stack.append(self)
+        cls = rec._annotation()
+        self.ann = cls(self.name) if cls is not None else None
+        self.cpu0 = time.thread_time()
+        # the annotation's clock and ours start (and below, stop) back
+        # to back, so the profiler's event lasts as long as the record
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        handoff = self.ready is not None and exc[0] is None
+        if self.ann is not None and not handoff:
+            self.ann.__exit__(None, None, None)
+        cpu = time.thread_time() - self.cpu0
         rec = self.rec
         stack = rec._tl.stack
         # tolerate a mispaired exit (a span abandoned by an exception in
@@ -227,8 +284,26 @@ class _Span:
             stack.pop()
         if stack:
             stack.pop()
-        rec._write_span(self, self.t0, t1 - self.t0)
+        if handoff:
+            rec._await_ready(self, cpu)
+        else:
+            rec._write_span(self, self.t0, t1 - self.t0, cpu)
         return False
+
+    def _finish_when_ready(self, cpu: float) -> None:
+        """The waiter thread's body: the span ends when its array is
+        ready; ``cpu`` adds the waiter's own (near-zero) thread time to
+        the dispatching thread's."""
+        c0 = time.thread_time()
+        try:
+            self.ready.block_until_ready()
+        except Exception as exc:  # the caller's own sync raises it
+            self.attrs["error"] = type(exc).__name__
+        t1 = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        self.rec._write_span(self, self.t0, t1 - self.t0,
+                             cpu + time.thread_time() - c0)
 
 
 class TraceRecorder:
@@ -256,6 +331,10 @@ class TraceRecorder:
         self._wall0 = time.time()
         self._phases: dict[str, list] = {}  # name -> [count, total_s]
         self._events: dict[str, int] = {}   # name -> count
+        #: jax.profiler.TraceAnnotation, once jax is imported
+        self._ann_cls = None
+        #: threads still waiting to end an end_when_ready span
+        self._waiters: list[threading.Thread] = []
         # rotation state (SHEEP_TRACE_MAX_MB): byte budget for the
         # active file, current size, and the next segment number
         # (continuing past any segments an earlier recorder left)
@@ -275,7 +354,6 @@ class TraceRecorder:
             m = _SEG_RE.search(p)
             if m:
                 self._seg = max(self._seg, int(m.group(1)))
-        import sys
         self._emit({"k": "meta", "v": TRACE_VERSION, "pid": os.getpid(),
                     "t0": self._wall0,
                     "argv": [str(a) for a in sys.argv[:6]]})
@@ -284,6 +362,31 @@ class TraceRecorder:
         with self._lock:
             self._id += 1
             return self._id
+
+    def _annotation(self):
+        """``jax.profiler.TraceAnnotation`` once the process has imported
+        jax (never imported here), else None.  The first sighting also
+        installs the compile listener."""
+        cls = self._ann_cls
+        if cls is None:
+            jax_mod = sys.modules.get("jax")
+            if jax_mod is None:
+                return None
+            try:
+                cls = jax_mod.profiler.TraceAnnotation
+                _install_compile_listener(jax_mod.monitoring)
+            except AttributeError:  # jax is still being imported
+                return None
+            self._ann_cls = cls
+        return cls
+
+    def _await_ready(self, sp: _Span, cpu: float) -> None:
+        t = threading.Thread(target=sp._finish_when_ready, args=(cpu,),
+                             name=f"trace-ready-{sp.name}", daemon=True)
+        with self._lock:
+            self._waiters = [w for w in self._waiters if w.is_alive()]
+            self._waiters.append(t)
+        t.start()
 
     def _emit(self, rec: dict) -> None:
         line = json.dumps(rec, separators=(",", ":"),
@@ -367,16 +470,18 @@ class TraceRecorder:
             rec["rid"] = rid
         self._emit(rec)
 
-    def _write_span(self, sp: _Span, t0: float, dur: float) -> None:
+    def _write_span(self, sp: _Span, t0: float, dur: float,
+                    cpu: float) -> None:
         with self._lock:
             acc = self._phases.setdefault(sp.name, [0, 0.0])
             acc[0] += 1
             acc[1] += dur
         rec = {"k": "span", "name": sp.name, "id": sp.id,
                "par": sp.par,
-               "tid": threading.get_ident() & 0xFFFF,
+               "tid": sp.tid,
                "t": round(t0 - self._t0, 6),
                "dur": round(dur, 6),
+               "cpu": round(cpu, 6),
                "a": {k: _json_safe(v) for k, v in sp.attrs.items()}}
         if sp.rid is not None:
             rec["rid"] = sp.rid
@@ -394,7 +499,12 @@ class TraceRecorder:
 
     def close(self, seal: bool = True) -> None:
         """Flush, close, and (on a clean close) seal the ``.sum``
-        sidecar that lets ``sheep fsck`` vouch for the finished file."""
+        sidecar that lets ``sheep fsck`` vouch for the finished file.
+        Spans still waiting on a device array get a bounded wait first."""
+        with self._lock:
+            waiters, self._waiters = self._waiters, []
+        for w in waiters:
+            w.join(WAITER_JOIN_S)
         with self._lock:
             f, self._f = self._f, None
         if f is None:
@@ -477,6 +587,67 @@ def event(name: str, **attrs) -> None:
     rec = _current()
     if rec is not None:
         rec.event(name, attrs)
+
+
+def bind(fn):
+    """``fn`` made to run, on the thread it is handed to, as a child of
+    the span open here: that thread's spans and events name this span as
+    their ``par`` (the span that caused them), not none.  Disabled, or
+    with no span open, ``fn`` itself."""
+    rec = _current()
+    if rec is None:
+        return fn
+    stack = getattr(rec._tl, "stack", None)
+    if not stack:
+        return fn
+    parent = _ParentRef(stack[-1].id)
+
+    def bound(*args, **kwargs):
+        tl = rec._tl
+        prev = getattr(tl, "stack", None)
+        tl.stack = [parent]
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            tl.stack = prev
+    return bound
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: recorded (on the compiling thread) just before the compile event of a
+#: program the persistent cache served
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_listener_installed = False
+_compile_lock = threading.Lock()
+_compile_tl = threading.local()
+
+
+def _install_compile_listener(monitoring) -> None:
+    """Register :func:`_on_jax_duration` with ``jax.monitoring`` once per
+    process (jax keeps listeners for the process's life)."""
+    global _compile_listener_installed
+    with _compile_lock:
+        if _compile_listener_installed:
+            return
+        _compile_listener_installed = True
+    monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _on_jax_duration(event: str, secs: float, **kwargs) -> None:
+    """One ``jax.compile`` event per backend compile, while a recorder is
+    open: the program's ``fun_name``, its seconds, and ``cached`` — True
+    where the persistent compilation cache served it."""
+    if event == _CACHE_HIT_EVENT:
+        _compile_tl.hit = True
+        return
+    if event != _COMPILE_EVENT:
+        return
+    cached = getattr(_compile_tl, "hit", False)
+    _compile_tl.hit = False
+    rec = _current()
+    if rec is not None:
+        rec.event("jax.compile", {"fun_name": kwargs.get("fun_name"),
+                                  "s": round(secs, 6), "cached": cached})
 
 
 SAMPLE_ENV = "SHEEP_TRACE_SAMPLE"

@@ -172,8 +172,9 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
     ``perf`` — optional dict receiving the reduce+fetch breakdown and
     speculation counters (loop_s / fetch_tail_s / overlap / spec_* —
     see reduce_and_fetch_links), for bench/profile observability.  The
-    same breakdown, with the device rounds and the live links at the
-    handoff, lands as one ``hybrid.handoff`` trace event.
+    device rounds, the live links at the handoff and the handoff's mode
+    land as one ``hybrid.handoff`` trace event; its times are the
+    ``reduce.loop`` and ``handoff.*`` spans.
 
     ``seq`` — an externally given elimination order (the `-s`/`-r` case):
     skips the device degree histogram + sort entirely (two fewer full-E
@@ -248,11 +249,15 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
         # immediate-handoff platforms).  Keep the original lo handle so
         # pst can still be materialized on device when nothing else
         # counts it.
-        with obs.span("prep.device", n=n):
+        # the span ends when the program's output is ready on the
+        # device (observed off this thread, only while tracing), not at
+        # the enqueue; m is never donated
+        with obs.span("prep", n=n) as sp:
             dev_seq, _, m, lo, hi, pst = prepare_links(
                 jnp.asarray(tail), jnp.asarray(head), n,
                 with_pst=host_edges is None
                 and not (stream_handoff_enabled() and handoff_input_ok()))
+            sp.end_when_ready(m)
         # full-graph prep: every vid holds a position, so the link
         # multiset carries no maskable pst-only records — the streaming
         # fold may accumulate pst when the loop skips straight to handoff
@@ -296,7 +301,7 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
             except Exception as exc:  # re-raised by _join_prefetch
                 fetched["error"] = exc
 
-        pre = threading.Thread(target=_prefetch, daemon=True)
+        pre = threading.Thread(target=obs.bind(_prefetch), daemon=True)
         pre.start()
 
     def _join_prefetch():
@@ -333,8 +338,7 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
     obs.event("hybrid.handoff", kind=res[0], rounds=int(res[4]),
               live=int(res[3]),
               **{k: perf[k] for k in ("stream_mode", "fetch_windows",
-                                      "handoff_links", "packed_handoff",
-                                      "loop_s", "fetch_tail_s")
+                                      "handoff_links", "packed_handoff")
                  if k in perf})
     if res[0] == "device":  # converged before the handoff threshold
         _, a, b, live, rounds = res
@@ -477,7 +481,8 @@ class _StreamFetcher:
             self._dev = pack_links_6b(lo, hi)
         else:
             self._dev = (lo.astype(jnp.int32), hi.astype(jnp.int32))
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(target=obs.bind(self._run),
+                                        daemon=True)
         if autostart:
             self._thread.start()
 

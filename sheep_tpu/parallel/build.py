@@ -298,7 +298,9 @@ def _selfcheck_forest(seq, forest, what: str):
         return seq, forest
     from ..core.validate import check_forest_fast
     from ..integrity.errors import IntegrityError
-    problems = check_forest_fast(forest)
+    from ..obs import trace as obs
+    with obs.span("build.selfcheck", n=len(forest.parent)):
+        problems = check_forest_fast(forest)
     if problems:
         raise IntegrityError(
             f"{what} produced an invalid forest: " + "; ".join(problems))

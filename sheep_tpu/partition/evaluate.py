@@ -29,6 +29,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..obs import trace as obs
+
 _CORMEN_MULT = np.uint64(2654435769)  # floor(0.5*(sqrt(5)-1) * 2^32)
 
 
@@ -84,67 +86,83 @@ def evaluate_partition(parts: np.ndarray, tail: np.ndarray, head: np.ndarray,
     reference's evaluate(graph) overload, partition.cpp:428-473); the
     ECV(down)/(up) fields then come back zero — print with
     ``with_seq=False``."""
+    # one span, with a child for each of the evaluator's four steps
+    with obs.span("partition.evaluate", records=len(tail),
+                  keys=2 * len(tail), parts=num_parts, streamed=False):
+        return _evaluate(parts, tail, head, seq, num_parts, max_vid,
+                         file_edges)
+
+
+def _evaluate(parts, tail, head, seq, num_parts, max_vid, file_edges):
     from ..core.sequence import sequence_positions
 
-    parts = parts.astype(np.int64)
-    t = tail.astype(np.int64)
-    h = head.astype(np.int64)
-    E = file_edges if file_edges is not None else len(t)
-    pos = None
-    if seq is not None:
-        pos = sequence_positions(seq, max_vid).astype(np.int64)
+    with obs.span("evaluate.gather"):
+        parts = parts.astype(np.int64)
+        t = tail.astype(np.int64)
+        h = head.astype(np.int64)
+        E = file_edges if file_edges is not None else len(t)
+        pos = None
+        if seq is not None:
+            pos = sequence_positions(seq, max_vid).astype(np.int64)
 
-    deg_mask = np.zeros(len(parts), dtype=bool)
-    deg_mask[t] = True
-    deg_mask[h] = True
-    n_active = int(deg_mask.sum())
-    P = max(int(parts.max(initial=0)) + 1, 1)
+        deg_mask = np.zeros(len(parts), dtype=bool)
+        deg_mask[t] = True
+        deg_mask[h] = True
+        n_active = int(deg_mask.sum())
+        P = max(int(parts.max(initial=0)) + 1, 1)
 
-    pt, ph = parts[t], parts[h]
+        pt, ph = parts[t], parts[h]
 
-    # edges cut: once per record, self-loops never differ
-    edges_cut = int((pt != ph).sum())
+        # edges cut: once per record, self-loops never differ
+        edges_cut = int((pt != ph).sum())
 
-    # directed-doubled views
-    X = np.concatenate([t, h])
-    Y = np.concatenate([h, t])
-    pX = np.concatenate([pt, ph])
-    pY = np.concatenate([ph, pt])
+        # directed-doubled views
+        X = np.concatenate([t, h])
+        Y = np.concatenate([h, t])
+        pX = np.concatenate([pt, ph])
+        pY = np.concatenate([ph, pt])
 
-    # Vcom_vol: distinct (X, part[Y]) pairs, seeded with (X, part[X])
-    active = np.nonzero(deg_mask)[0]
-    vx = np.concatenate([X, active])
-    vy = np.concatenate([pY, parts[active]])
-    vcom = _nunique_pairs(vx, vy, P) - n_active
+    with obs.span("evaluate.vcom"):
+        # Vcom_vol: distinct (X, part[Y]) pairs, seeded with (X, part[X])
+        active = np.nonzero(deg_mask)[0]
+        vx = np.concatenate([X, active])
+        vy = np.concatenate([pY, parts[active]])
+        vcom = _nunique_pairs(vx, vy, P) - n_active
 
-    # ECV(hash): per directed edge, part of the hash-smaller endpoint
-    hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
-    hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
-    hash_part = np.where(hX < hY, pX, pY)
-    ecv_hash = _nunique_pairs(X, hash_part, P) - n_active
-    # hash balance: once per undirected edge (the directed X<Y filter),
-    # self-loops skipped; record orientation must not matter
-    und = t != h
-    a = np.minimum(t[und], h[und])
-    b = np.maximum(t[und], h[und])
-    ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
-    hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
-    und_hash_part = np.where(ha < hb, parts[a], parts[b])
-    hash_balance = int(np.bincount(und_hash_part, minlength=P).max(initial=0))
+    with obs.span("evaluate.ecv_hash"):
+        # ECV(hash): per directed edge, part of the hash-smaller endpoint
+        hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
+        hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
+        hash_part = np.where(hX < hY, pX, pY)
+        ecv_hash = _nunique_pairs(X, hash_part, P) - n_active
+        # hash balance: once per undirected edge (the directed X<Y
+        # filter), self-loops skipped; record orientation must not matter
+        und = t != h
+        a = np.minimum(t[und], h[und])
+        b = np.maximum(t[und], h[und])
+        ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
+        hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
+        und_hash_part = np.where(ha < hb, parts[a], parts[b])
+        hash_balance = int(np.bincount(und_hash_part,
+                                       minlength=P).max(initial=0))
 
-    # ECV(down)/(up): part of the earlier/later-in-sequence endpoint
-    ecv_down = ecv_up = down_balance = up_balance = 0
-    if pos is not None:
-        posX = pos[X]
-        posY = pos[Y]
-        down_part = np.where(posX < posY, pX, pY)
-        up_part = np.where(posX > posY, pX, pY)
-        ecv_down = _nunique_pairs(X, down_part, P) - n_active
-        ecv_up = _nunique_pairs(X, up_part, P) - n_active
-        down_balance = int(np.bincount(pX[posX < posY], minlength=P).max(initial=0))
-        up_balance = int(np.bincount(pX[posX > posY], minlength=P).max(initial=0))
+    with obs.span("evaluate.ecv_seq"):
+        # ECV(down)/(up): part of the earlier/later-in-sequence endpoint
+        ecv_down = ecv_up = down_balance = up_balance = 0
+        if pos is not None:
+            posX = pos[X]
+            posY = pos[Y]
+            down_part = np.where(posX < posY, pX, pY)
+            up_part = np.where(posX > posY, pX, pY)
+            ecv_down = _nunique_pairs(X, down_part, P) - n_active
+            ecv_up = _nunique_pairs(X, up_part, P) - n_active
+            down_balance = int(np.bincount(pX[posX < posY],
+                                           minlength=P).max(initial=0))
+            up_balance = int(np.bincount(pX[posX > posY],
+                                         minlength=P).max(initial=0))
 
-    vertex_balance = int(np.bincount(parts[active], minlength=P).max(initial=0))
+        vertex_balance = int(np.bincount(parts[active],
+                                         minlength=P).max(initial=0))
 
     return EvalReport(
         edges_cut=edges_cut,
@@ -184,6 +202,16 @@ def evaluate_partition_streamed(parts: np.ndarray, blocks_factory,
     available (sheep_eval_block, ~4x at 1.476B edges), with the numpy
     body as the oracle/fallback.
     """
+    # one span, with two children: the block scan (every window's pass
+    # over the stream) and the final balance count
+    with obs.span("partition.evaluate", records=file_edges,
+                  keys=2 * file_edges, parts=num_parts, streamed=True):
+        return _evaluate_streamed(parts, blocks_factory, pos, num_parts,
+                                  file_edges, impl)
+
+
+def _evaluate_streamed(parts, blocks_factory, pos, num_parts, file_edges,
+                       impl):
     parts = np.ascontiguousarray(parts, dtype=np.int64)
     n = len(parts)
     P = max(int(parts.max(initial=0)) + 1, 1)
@@ -202,77 +230,90 @@ def evaluate_partition_streamed(parts: np.ndarray, blocks_factory,
     up_loads = np.zeros(P, dtype=np.int64)
     vcom = ecv_hash = ecv_down = ecv_up = 0
 
-    for w0 in range(0, P, 64):
-        first_window = w0 == 0
-        m_vcom = np.zeros(n, dtype=np.uint64)
-        m_hash = np.zeros(n, dtype=np.uint64)
-        m_down = np.zeros(n, dtype=np.uint64) if pos is not None else None
-        m_up = np.zeros(n, dtype=np.uint64) if pos is not None else None
+    with obs.span("evaluate.scan", windows=-(-P // 64)):
+        for w0 in range(0, P, 64):
+            first_window = w0 == 0
+            m_vcom = np.zeros(n, dtype=np.uint64)
+            m_hash = np.zeros(n, dtype=np.uint64)
+            seqd = pos is not None
+            m_down = np.zeros(n, dtype=np.uint64) if seqd else None
+            m_up = np.zeros(n, dtype=np.uint64) if seqd else None
 
-        def scatter_bits(mask, X, p):
-            sel = (p >= w0) & (p < w0 + 64)
-            np.bitwise_or.at(mask, X[sel],
-                             np.uint64(1) << (p[sel] - w0).astype(np.uint64))
+            def scatter_bits(mask, X, p):
+                sel = (p >= w0) & (p < w0 + 64)
+                np.bitwise_or.at(
+                    mask, X[sel],
+                    np.uint64(1) << (p[sel] - w0).astype(np.uint64))
 
-        for tail, head in blocks_factory():
-            if native is not None:
-                # one C pass per block updates every window bitmap / load
-                # counter in place — bit-identical to the numpy body
-                # below, ~40x faster (np.bitwise_or.at is unbuffered)
-                edges_cut += native.eval_block(
-                    tail, head, parts, pos32, w0, first_window,
-                    m_vcom, m_hash, m_down, m_up, deg_mask,
-                    hash_loads, down_loads, up_loads, P)
-                continue
-            t = tail.astype(np.int64)
-            h = head.astype(np.int64)
-            pt, ph = parts[t], parts[h]
-            if first_window:
-                deg_mask[t] = 1
-                deg_mask[h] = 1
-                edges_cut += int((pt != ph).sum())
+            for tail, head in blocks_factory():
+                if native is not None:
+                    # one C pass per block updates every window bitmap /
+                    # load counter in place — bit-identical to the numpy
+                    # body below, ~40x faster (np.bitwise_or.at is
+                    # unbuffered)
+                    edges_cut += native.eval_block(
+                        tail, head, parts, pos32, w0, first_window,
+                        m_vcom, m_hash, m_down, m_up, deg_mask,
+                        hash_loads, down_loads, up_loads, P)
+                    continue
+                t = tail.astype(np.int64)
+                h = head.astype(np.int64)
+                pt, ph = parts[t], parts[h]
+                if first_window:
+                    deg_mask[t] = 1
+                    deg_mask[h] = 1
+                    edges_cut += int((pt != ph).sum())
 
-            for X, Y, pX, pY in ((t, h, pt, ph), (h, t, ph, pt)):
-                scatter_bits(m_vcom, X, pY)
-                hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
-                hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
-                scatter_bits(m_hash, X, np.where(hX < hY, pX, pY))
-                if pos is not None:
-                    posX, posY = pos[X], pos[Y]
-                    scatter_bits(m_down, X, np.where(posX < posY, pX, pY))
-                    scatter_bits(m_up, X, np.where(posX > posY, pX, pY))
+                for X, Y, pX, pY in ((t, h, pt, ph), (h, t, ph, pt)):
+                    scatter_bits(m_vcom, X, pY)
+                    hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
+                    hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
+                    scatter_bits(m_hash, X, np.where(hX < hY, pX, pY))
+                    if pos is not None:
+                        posX, posY = pos[X], pos[Y]
+                        scatter_bits(m_down, X,
+                                     np.where(posX < posY, pX, pY))
+                        scatter_bits(m_up, X,
+                                     np.where(posX > posY, pX, pY))
 
-            if first_window:
-                und = t != h
-                a = np.minimum(t[und], h[und])
-                b = np.maximum(t[und], h[und])
-                ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
-                hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
-                hash_loads += np.bincount(
-                    np.where(ha < hb, parts[a], parts[b]), minlength=P)
-                if pos is not None:
-                    post, posh = pos[t], pos[h]
-                    down_loads += np.bincount(pt[post < posh], minlength=P)
-                    up_loads += np.bincount(pt[post > posh], minlength=P)
-                    down_loads += np.bincount(ph[posh < post], minlength=P)
-                    up_loads += np.bincount(ph[posh > post], minlength=P)
+                if first_window:
+                    und = t != h
+                    a = np.minimum(t[und], h[und])
+                    b = np.maximum(t[und], h[und])
+                    ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
+                    hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
+                    hash_loads += np.bincount(
+                        np.where(ha < hb, parts[a], parts[b]), minlength=P)
+                    if pos is not None:
+                        post, posh = pos[t], pos[h]
+                        down_loads += np.bincount(pt[post < posh],
+                                                  minlength=P)
+                        up_loads += np.bincount(pt[post > posh],
+                                                minlength=P)
+                        down_loads += np.bincount(ph[posh < post],
+                                                  minlength=P)
+                        up_loads += np.bincount(ph[posh > post],
+                                                minlength=P)
 
-        # Seed Vcom with each active vertex's own part (within this window).
+            # Seed Vcom with each active vertex's own part (within this
+            # window).
+            active = np.nonzero(deg_mask)[0]
+            own = parts[active]
+            sel = (own >= w0) & (own < w0 + 64)
+            np.bitwise_or.at(
+                m_vcom, active[sel],
+                np.uint64(1) << (own[sel] - w0).astype(np.uint64))
+
+            vcom += int(np.bitwise_count(m_vcom).sum())
+            ecv_hash += int(np.bitwise_count(m_hash).sum())
+            if pos is not None:
+                ecv_down += int(np.bitwise_count(m_down).sum())
+                ecv_up += int(np.bitwise_count(m_up).sum())
+
+    with obs.span("evaluate.finish"):
         active = np.nonzero(deg_mask)[0]
-        own = parts[active]
-        sel = (own >= w0) & (own < w0 + 64)
-        np.bitwise_or.at(m_vcom, active[sel],
-                         np.uint64(1) << (own[sel] - w0).astype(np.uint64))
-
-        vcom += int(np.bitwise_count(m_vcom).sum())
-        ecv_hash += int(np.bitwise_count(m_hash).sum())
-        if pos is not None:
-            ecv_down += int(np.bitwise_count(m_down).sum())
-            ecv_up += int(np.bitwise_count(m_up).sum())
-
-    active = np.nonzero(deg_mask)[0]
-    n_active = len(active)
-    part_loads = np.bincount(parts[active], minlength=P)
+        n_active = len(active)
+        part_loads = np.bincount(parts[active], minlength=P)
 
     return EvalReport(
         edges_cut=edges_cut,

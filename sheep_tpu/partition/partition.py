@@ -16,6 +16,7 @@ from .. import INVALID_PART
 from ..core.forest import Forest
 from ..core.sequence import sequence_positions
 from ..io.edges import write_net, write_dat
+from ..obs import trace as obs
 from .tree_partition import TreePartitionOptions, partition_forest
 
 
@@ -30,12 +31,14 @@ class Partition:
                     strategy: str = "forward",
                     max_vid: int | None = None,
                     pre: np.ndarray | None = None) -> "Partition":
-        jparts = partition_forest(forest, num_parts, opts, strategy, pre=pre)
-        n = int(max_vid) + 1 if max_vid is not None else 0
-        n = max(n, (int(seq.max()) + 1) if len(seq) else 0)
-        vparts = np.full(n, INVALID_PART, dtype=np.int64)
-        vparts[seq] = jparts
-        return cls(vparts, num_parts)
+        with obs.span("partition.tree", parts=num_parts, nodes=len(seq)):
+            jparts = partition_forest(forest, num_parts, opts, strategy,
+                                      pre=pre)
+            n = int(max_vid) + 1 if max_vid is not None else 0
+            n = max(n, (int(seq.max()) + 1) if len(seq) else 0)
+            vparts = np.full(n, INVALID_PART, dtype=np.int64)
+            vparts[seq] = jparts
+            return cls(vparts, num_parts)
 
     @classmethod
     def from_file(cls, seq: np.ndarray, filename: str) -> "Partition":

@@ -28,9 +28,10 @@ from .common import PhaseClock, print_phase
 
 USAGE = "USAGE: partition_tree [options] input_sequence input_tree parts [parts...]"
 
-#: .dat record count beyond which the evaluate mode streams blocks through
-#: the O(n)-memory evaluator (override: SHEEP_EVAL_STREAM=1 forces it on,
-#: =0 off, SHEEP_EVAL_STREAM_THRESHOLD sets the record count).
+#: .dat record count beyond which the evaluate mode reads the edges in file
+#: blocks instead of loading them: the same bitmap evaluator, in O(n) memory
+#: (override: SHEEP_EVAL_STREAM=1 forces it on, =0 off,
+#: SHEEP_EVAL_STREAM_THRESHOLD sets the record count).
 _STREAM_THRESHOLD = 1 << 27
 
 
@@ -173,9 +174,9 @@ def _main(argv: list[str] | None) -> int:
             part = Partition.from_forest(seq, forest, num_parts, popts)
             part.print()
     elif output_filename == "":
-        # Partition and evaluate.  Large .dat graphs stream through the
-        # O(n)-memory evaluator instead of materializing doubled key arrays
-        # (which peak ~50 GB at twitter scale); same numbers either way.
+        # Partition and evaluate.  Large .dat graphs are read in file
+        # blocks instead of loaded whole, so their records never sit in
+        # memory at once; same numbers either way.
         if _streamed_eval_wanted(graph_filename, sequence_filename):
             _evaluate_streamed(graph_filename, sequence_filename, forest,
                                popts, pre_weight, args[2:], verbose)

@@ -136,8 +136,8 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.sheep_eval_block.restype = ctypes.c_int64
     lib.sheep_eval_block.argtypes = [
         _u32p, _u32p, ctypes.c_int64, _i64p, ctypes.c_int64,
-        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int32,
-        _u64p, _u64p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_int32, _u64p, _u64p, ctypes.c_void_p, ctypes.c_void_p,
         _u8p, _i64p, _i64p, _i64p, ctypes.c_int64]
     lib.sheep_native_omp.restype = ctypes.c_int
     lib.sheep_native_omp.argtypes = []
@@ -539,27 +539,34 @@ def degree_sequence_from_degrees(deg: np.ndarray) -> np.ndarray | None:
 
 
 def eval_block(tail: np.ndarray, head: np.ndarray, parts: np.ndarray,
-               pos: np.ndarray | None, w0: int, first_window: bool,
+               pos: np.ndarray | None, w0: int, words: int,
+               first_window: bool,
                m_vcom: np.ndarray, m_hash: np.ndarray,
                m_down: np.ndarray | None, m_up: np.ndarray | None,
                deg_mask: np.ndarray, hash_loads: np.ndarray,
                down_loads: np.ndarray, up_loads: np.ndarray,
                num_parts: int) -> int:
-    """One block of the streamed partition evaluator (updates the window
-    bitmaps / load counters in place); returns the edges_cut increment.
-    All array arguments must be the caller-owned state buffers — they are
-    mutated, not copied.
+    """One block of the part-set bitmap evaluator over the parts
+    [w0, w0 + 64*words) (updates the bitmaps of ``len(parts) * words``
+    uint64 and the load counters in place); returns the edges_cut
+    increment.  All array arguments must be the caller-owned state
+    buffers — they are mutated, not copied.
     """
     lib = _load()
     assert lib is not None
     tail = np.ascontiguousarray(tail, dtype=np.uint32)
     head = np.ascontiguousarray(head, dtype=np.uint32)
+    # the kernel indexes X*words + word, and writes m_down/m_up whenever
+    # pos is given: a short or missing bitmap would be a heap overrun or a
+    # null-pointer store
+    size = len(parts) * words
+    maps = (m_vcom, m_hash) if pos is None else (m_vcom, m_hash, m_down, m_up)
+    if words < 1 or len(deg_mask) < len(parts) or \
+            any(m is None or m.size != size for m in maps):
+        raise ValueError(f"eval_block: bitmaps must hold len(parts) x "
+                         f"words = {size} uint64, deg_mask len(parts)")
     pos_ptr, pos_len = 0, 0
     if pos is not None:
-        # the C kernel writes m_down/m_up whenever pos is given — a
-        # missing mask would be a null-pointer store
-        assert m_down is not None and m_up is not None, \
-            "pos requires both m_down and m_up buffers"
         assert pos.dtype == np.uint32 and pos.flags["C_CONTIGUOUS"]
         pos_ptr, pos_len = pos.ctypes.data, len(pos)
         assert m_down.dtype == np.uint64 and m_down.flags["C_CONTIGUOUS"]
@@ -571,8 +578,8 @@ def eval_block(tail: np.ndarray, head: np.ndarray, parts: np.ndarray,
     up_ptr = m_up.ctypes.data if pos is not None else 0
     rc = lib.sheep_eval_block(
         tail, head, len(tail), parts, len(parts), pos_ptr, pos_len,
-        w0, 1 if first_window else 0, m_vcom, m_hash, down_ptr, up_ptr,
-        deg_mask, hash_loads, down_loads, up_loads, num_parts)
+        w0, words, 1 if first_window else 0, m_vcom, m_hash, down_ptr,
+        up_ptr, deg_mask, hash_loads, down_loads, up_loads, num_parts)
     if rc < 0:
         raise ValueError(
             "sheep_eval_block: a vid is out of range of parts/pos, or a "

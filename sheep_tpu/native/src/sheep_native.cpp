@@ -1774,24 +1774,31 @@ int sheep_fennel_edges(const uint32_t* tail, const uint32_t* head, int64_t m,
   return 0;
 }
 
-// One edge block of the streamed O(n)-memory partition evaluator
-// (partition/evaluate.py evaluate_partition_streamed; reference metric
-// definitions at lib/partition.cpp:428-521).  Updates the caller's
-// window bitmaps / load counters in place; bit-identical to the python
-// block body.  ``pos`` may be null (sequence-free overload: m_down/m_up
-// untouched).  Returns the edges_cut increment (first window only,
-// else 0), or -1 on an out-of-range vid — the wrapper raises.
+// One edge block of the part-set bitmap evaluator (partition/evaluate.py;
+// reference metric definitions at lib/partition.cpp:428-521).  A pass
+// covers the parts [w0, w0 + 64*words): each bitmap holds ``words``
+// uint64 per vertex, vertex X's part p at bit (p-w0)%64 of word
+// X*words + (p-w0)/64.  Updates the caller's bitmaps / load counters in
+// place; bit-identical to the python block body.  ``pos`` may be null
+// (sequence-free overload: m_down/m_up untouched).  Returns the
+// edges_cut increment (first pass only, else 0), or -1 on an
+// out-of-range vid — the wrapper raises.
 int64_t sheep_eval_block(const uint32_t* tail, const uint32_t* head,
                          int64_t e, const int64_t* parts, int64_t n,
                          const uint32_t* pos, int64_t pos_len,
-                         int64_t w0, int32_t first_window,
+                         int64_t w0, int64_t words, int32_t first_window,
                          uint64_t* m_vcom, uint64_t* m_hash,
                          uint64_t* m_down, uint64_t* m_up,
                          uint8_t* deg_mask, int64_t* hash_loads,
                          int64_t* down_loads, int64_t* up_loads,
                          int64_t num_parts) {
   constexpr uint32_t kMult = 2654435769u;  // floor(0.5*(sqrt(5)-1)*2^32)
-  const int64_t w_hi = w0 + 64;
+  const uint64_t span = 64 * (uint64_t)words;
+  // set part p's bit in vertex X's row when p lies in this pass
+  auto mark = [&](uint64_t* m, uint32_t X, int64_t p) {
+    const uint64_t d = (uint64_t)(p - w0);  // negative p wraps past span
+    if (d < span) m[(size_t)X * words + d / 64] |= 1ull << (d % 64);
+  };
   int64_t edges_cut = 0;
   for (int64_t i = 0; i < e; ++i) {
     const uint32_t t = tail[i], h = head[i];
@@ -1810,14 +1817,11 @@ int64_t sheep_eval_block(const uint32_t* tail, const uint32_t* head,
       const int64_t pX = dir ? ph : pt, pY = dir ? pt : ph;
       const uint32_t hX = dir ? hh : ht, hY = dir ? ht : hh;
       const uint32_t sX = dir ? posh : post, sY = dir ? post : posh;
-      if (pY >= w0 && pY < w_hi) m_vcom[X] |= 1ull << (pY - w0);
-      const int64_t p_hash = hX < hY ? pX : pY;
-      if (p_hash >= w0 && p_hash < w_hi) m_hash[X] |= 1ull << (p_hash - w0);
+      mark(m_vcom, X, pY);
+      mark(m_hash, X, hX < hY ? pX : pY);
       if (pos) {
-        const int64_t p_down = sX < sY ? pX : pY;
-        if (p_down >= w0 && p_down < w_hi) m_down[X] |= 1ull << (p_down - w0);
-        const int64_t p_up = sX > sY ? pX : pY;
-        if (p_up >= w0 && p_up < w_hi) m_up[X] |= 1ull << (p_up - w0);
+        mark(m_down, X, sX < sY ? pX : pY);
+        mark(m_up, X, sX > sY ? pX : pY);
       }
     }
     if (first_window) {

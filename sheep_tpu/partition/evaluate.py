@@ -1,9 +1,10 @@
-"""Partition quality evaluator — vectorized.
+"""Partition quality evaluator.
 
 Computes exactly the metrics of the reference's exhaustive evaluator
-(lib/partition.cpp:428-521), but as dense segment/unique operations instead
-of per-vertex hash-set scans ("evaluation is exhaustive, not efficient",
-reference README:105 — here it is both):
+(lib/partition.cpp:428-521), but with per-vertex part-set bitmaps (the C
+kernel, one pass over the edges) or sorts of (vertex, part) keys (the
+numpy oracle) instead of per-vertex hash-set scans ("evaluation is
+exhaustive, not efficient", reference README:105 — here it is both):
 
   edges cut   undirected edges whose endpoints differ in part
   Vcom. vol   communication volume: per vertex, distinct neighbor parts
@@ -81,16 +82,61 @@ class EvalReport:
 def evaluate_partition(parts: np.ndarray, tail: np.ndarray, head: np.ndarray,
                        seq: np.ndarray | None, num_parts: int,
                        max_vid: int | None = None,
-                       file_edges: int | None = None) -> EvalReport:
+                       file_edges: int | None = None,
+                       impl: str = "auto") -> EvalReport:
     """``seq=None`` evaluates the sequence-free metrics only (the
     reference's evaluate(graph) overload, partition.cpp:428-473); the
     ECV(down)/(up) fields then come back zero — print with
-    ``with_seq=False``."""
-    # one span, with a child for each of the evaluator's four steps
+    ``with_seq=False``.
+
+    ``impl``: auto|native|python.  With the native runtime the distinct
+    (vertex, part) counts are part-set bitmaps that the C kernel
+    (sheep_eval_block) fills in one pass over the loaded arrays; "python",
+    or a missing runtime under "auto", runs the sort body, the oracle.
+    The two give equal reports."""
+    from ..core.forest import native_or_none
+    native = native_or_none(impl)
+    # one span, with a child for each of the body's steps
     with obs.span("partition.evaluate", records=len(tail),
-                  keys=2 * len(tail), parts=num_parts, streamed=False):
-        return _evaluate(parts, tail, head, seq, num_parts, max_vid,
-                         file_edges)
+                  keys=2 * len(tail), parts=num_parts, streamed=False,
+                  kernel="sort" if native is None else "bitmap"):
+        if native is None:
+            return _evaluate(parts, tail, head, seq, num_parts, max_vid,
+                             file_edges)
+        return _evaluate_bitmap(parts, tail, head, seq, num_parts, max_vid,
+                                file_edges, native)
+
+
+def _part_count(parts: np.ndarray) -> int:
+    return max(int(parts.max(initial=0)) + 1, 1)
+
+
+def _pass_words(n: int, e: int, P: int, maps: int) -> tuple[int, int]:
+    """(words, passes) of the in-memory bitmap scan.  A pass covers
+    ``words`` 64-part windows; its ``maps`` bitmaps of n x words uint64
+    stay within the bytes of the sort body's doubled int64 key array
+    (16 a record), at least one window a pass, and the passes split the
+    windows evenly."""
+    windows = -(-P // 64)
+    fit = max(1, 16 * e // (8 * maps * max(n, 1)))
+    passes = -(-windows // fit)
+    return -(-windows // passes), passes
+
+
+def _evaluate_bitmap(parts, tail, head, seq, num_parts, max_vid, file_edges,
+                     native):
+    from ..core.sequence import sequence_positions
+
+    parts = np.ascontiguousarray(parts, dtype=np.int64)
+    tail = np.ascontiguousarray(tail, dtype=np.uint32)
+    head = np.ascontiguousarray(head, dtype=np.uint32)
+    pos = None if seq is None else sequence_positions(seq, max_vid)
+    P = _part_count(parts)
+    words, passes = _pass_words(len(parts), len(tail), P,
+                                2 if pos is None else 4)
+    E = file_edges if file_edges is not None else len(tail)
+    return _bitmap_eval(parts, P, lambda: ((tail, head),), pos, num_parts,
+                        E, native, words, passes=passes, source="memory")
 
 
 def _evaluate(parts, tail, head, seq, num_parts, max_vid, file_edges):
@@ -109,7 +155,7 @@ def _evaluate(parts, tail, head, seq, num_parts, max_vid, file_edges):
         deg_mask[t] = True
         deg_mask[h] = True
         n_active = int(deg_mask.sum())
-        P = max(int(parts.max(initial=0)) + 1, 1)
+        P = _part_count(parts)
 
         pt, ph = parts[t], parts[h]
 
@@ -189,10 +235,11 @@ def evaluate_partition_streamed(parts: np.ndarray, blocks_factory,
     twitter-2010; reference anchor lib/partition.cpp:428-521).
 
     The distinct-(vertex, part) counts behind Vcom/ECV are computed with
-    per-vertex part-set *bitmaps*: one uint64 per vertex covers a window of
-    64 parts, edges stream through in blocks, and windows repeat for
-    num_parts > 64 — ceil(P/64) passes over the edge stream, each O(n)
-    memory.  Results are bit-identical to :func:`evaluate_partition`.
+    per-vertex part-set *bitmaps*, as in :func:`evaluate_partition`'s
+    native path: one uint64 per vertex covers a window of 64 parts, edges
+    stream through in blocks, and windows repeat for num_parts > 64 —
+    ceil(P/64) passes over the edge stream, each O(n) memory.  Results
+    are bit-identical to :func:`evaluate_partition`.
 
     ``blocks_factory``: zero-arg callable returning a fresh iterator of
     (tail, head) uint32 blocks (e.g. ``lambda: iter_dat_blocks(path, B)``).
@@ -212,115 +259,71 @@ def evaluate_partition_streamed(parts: np.ndarray, blocks_factory,
 
 def _evaluate_streamed(parts, blocks_factory, pos, num_parts, file_edges,
                        impl):
-    parts = np.ascontiguousarray(parts, dtype=np.int64)
-    n = len(parts)
-    P = max(int(parts.max(initial=0)) + 1, 1)
-
     from ..core.forest import native_or_none
-    native = native_or_none(impl)
-    pos32 = None
-    if pos is not None and native is not None:
-        pos32 = np.ascontiguousarray(pos, dtype=np.uint32)
 
+    parts = np.ascontiguousarray(parts, dtype=np.int64)
+    P = _part_count(parts)
+    if pos is not None:
+        pos = np.ascontiguousarray(pos, dtype=np.uint32)
+    # one 64-part window a pass: the O(n) memory contract
+    return _bitmap_eval(parts, P, blocks_factory, pos, num_parts, file_edges,
+                        native_or_none(impl), 1)
+
+
+def _bitmap_eval(parts, P, blocks_factory, pos, num_parts, file_edges,
+                 native, words, **scan_attrs):
+    """The part-set bitmap body of both evaluators.  A pass covers the
+    parts [w0, w0 + 64*words): every block of ``blocks_factory()`` ORs one
+    bit per (vertex, part) into the bitmaps (``native.eval_block``, or
+    :func:`_eval_block_python` without the runtime); the pass then seeds
+    Vcom with each active vertex's own part and popcounts.  The first pass
+    also counts the cut, the active vertices and the balances' loads.
+    ``parts`` int64, ``pos`` uint32 or None."""
+    n = len(parts)
+    width = 64 * words
+    bitmaps = [np.zeros(n * words, dtype=np.uint64)
+               for _ in range(2 if pos is None else 4)]
+    m_vcom, m_hash, m_down, m_up = bitmaps + [None] * (4 - len(bitmaps))
     deg_mask = np.zeros(n, dtype=np.uint8)
+    hash_loads, down_loads, up_loads = (np.zeros(P, dtype=np.int64)
+                                        for _ in range(3))
+    block = _eval_block_python if native is None else native.eval_block
     edges_cut = 0
-    part_loads = np.zeros(P, dtype=np.int64)          # vertex balance
-    hash_loads = np.zeros(P, dtype=np.int64)          # undirected hash loads
-    down_loads = np.zeros(P, dtype=np.int64)
-    up_loads = np.zeros(P, dtype=np.int64)
-    vcom = ecv_hash = ecv_down = ecv_up = 0
+    # distinct (vertex, part) pairs of Vcom, ECV(hash), ECV(down), ECV(up)
+    pairs = [0] * len(bitmaps)
 
-    with obs.span("evaluate.scan", windows=-(-P // 64)):
-        for w0 in range(0, P, 64):
-            first_window = w0 == 0
-            m_vcom = np.zeros(n, dtype=np.uint64)
-            m_hash = np.zeros(n, dtype=np.uint64)
-            seqd = pos is not None
-            m_down = np.zeros(n, dtype=np.uint64) if seqd else None
-            m_up = np.zeros(n, dtype=np.uint64) if seqd else None
-
-            def scatter_bits(mask, X, p):
-                sel = (p >= w0) & (p < w0 + 64)
-                np.bitwise_or.at(
-                    mask, X[sel],
-                    np.uint64(1) << (p[sel] - w0).astype(np.uint64))
-
+    with obs.span("evaluate.scan", windows=-(-P // 64), **scan_attrs):
+        for w0 in range(0, P, width):
+            if w0:
+                for m in bitmaps:
+                    m.fill(0)
             for tail, head in blocks_factory():
-                if native is not None:
-                    # one C pass per block updates every window bitmap /
-                    # load counter in place — bit-identical to the numpy
-                    # body below, ~40x faster (np.bitwise_or.at is
-                    # unbuffered)
-                    edges_cut += native.eval_block(
-                        tail, head, parts, pos32, w0, first_window,
-                        m_vcom, m_hash, m_down, m_up, deg_mask,
-                        hash_loads, down_loads, up_loads, P)
-                    continue
-                t = tail.astype(np.int64)
-                h = head.astype(np.int64)
-                pt, ph = parts[t], parts[h]
-                if first_window:
-                    deg_mask[t] = 1
-                    deg_mask[h] = 1
-                    edges_cut += int((pt != ph).sum())
-
-                for X, Y, pX, pY in ((t, h, pt, ph), (h, t, ph, pt)):
-                    scatter_bits(m_vcom, X, pY)
-                    hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
-                    hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
-                    scatter_bits(m_hash, X, np.where(hX < hY, pX, pY))
-                    if pos is not None:
-                        posX, posY = pos[X], pos[Y]
-                        scatter_bits(m_down, X,
-                                     np.where(posX < posY, pX, pY))
-                        scatter_bits(m_up, X,
-                                     np.where(posX > posY, pX, pY))
-
-                if first_window:
-                    und = t != h
-                    a = np.minimum(t[und], h[und])
-                    b = np.maximum(t[und], h[und])
-                    ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
-                    hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
-                    hash_loads += np.bincount(
-                        np.where(ha < hb, parts[a], parts[b]), minlength=P)
-                    if pos is not None:
-                        post, posh = pos[t], pos[h]
-                        down_loads += np.bincount(pt[post < posh],
-                                                  minlength=P)
-                        up_loads += np.bincount(pt[post > posh],
-                                                minlength=P)
-                        down_loads += np.bincount(ph[posh < post],
-                                                  minlength=P)
-                        up_loads += np.bincount(ph[posh > post],
-                                                minlength=P)
-
+                edges_cut += block(tail, head, parts, pos, w0, words, w0 == 0,
+                                   m_vcom, m_hash, m_down, m_up, deg_mask,
+                                   hash_loads, down_loads, up_loads, P)
             # Seed Vcom with each active vertex's own part (within this
-            # window).
+            # pass); one bit a vertex, so no index repeats.
             active = np.nonzero(deg_mask)[0]
-            own = parts[active]
-            sel = (own >= w0) & (own < w0 + 64)
-            np.bitwise_or.at(
-                m_vcom, active[sel],
-                np.uint64(1) << (own[sel] - w0).astype(np.uint64))
-
-            vcom += int(np.bitwise_count(m_vcom).sum())
-            ecv_hash += int(np.bitwise_count(m_hash).sum())
-            if pos is not None:
-                ecv_down += int(np.bitwise_count(m_down).sum())
-                ecv_up += int(np.bitwise_count(m_up).sum())
+            d = parts[active] - w0
+            sel = (d >= 0) & (d < width)
+            d = d[sel]
+            m_vcom[active[sel] * words + d // 64] |= \
+                np.uint64(1) << (d % 64).astype(np.uint64)
+            for i, m in enumerate(bitmaps):
+                pairs[i] += int(np.bitwise_count(m).sum())
 
     with obs.span("evaluate.finish"):
         active = np.nonzero(deg_mask)[0]
         n_active = len(active)
         part_loads = np.bincount(parts[active], minlength=P)
 
+    seqd = pos is not None
     return EvalReport(
         edges_cut=edges_cut,
-        vcom_vol=vcom - n_active,
-        ecv_hash=ecv_hash - n_active,
-        ecv_down=(ecv_down - n_active) if pos is not None else 0,
-        ecv_up=(ecv_up - n_active) if pos is not None else 0,
+        vcom_vol=pairs[0] - n_active,
+        ecv_hash=pairs[1] - n_active,
+        ecv_down=pairs[2] - n_active if seqd else 0,
+        ecv_up=pairs[3] - n_active if seqd else 0,
         vertex_balance=int(part_loads.max(initial=0)),
         hash_balance=int(hash_loads.max(initial=0)),
         down_balance=int(down_loads.max(initial=0)),
@@ -329,3 +332,54 @@ def _evaluate_streamed(parts, blocks_factory, pos, num_parts, file_edges,
         num_nodes=n_active,
         num_parts=num_parts,
     )
+
+
+def _eval_block_python(tail, head, parts, pos, w0, words, first_window,
+                       m_vcom, m_hash, m_down, m_up, deg_mask, hash_loads,
+                       down_loads, up_loads, P):
+    """The numpy body of ``sheep_eval_block``, the oracle of the native
+    block: same arguments, same in-place updates, the cut's increment
+    returned (np.bitwise_or.at is unbuffered, and ~40x slower)."""
+    width = 64 * words
+
+    def scatter_bits(mask, X, p):
+        d = p - w0
+        sel = (d >= 0) & (d < width)
+        d = d[sel]
+        np.bitwise_or.at(mask, X[sel] * words + d // 64,
+                         np.uint64(1) << (d % 64).astype(np.uint64))
+
+    t = tail.astype(np.int64)
+    h = head.astype(np.int64)
+    pt, ph = parts[t], parts[h]
+    edges_cut = 0
+    if first_window:
+        deg_mask[t] = 1
+        deg_mask[h] = 1
+        edges_cut = int((pt != ph).sum())
+
+    for X, Y, pX, pY in ((t, h, pt, ph), (h, t, ph, pt)):
+        scatter_bits(m_vcom, X, pY)
+        hX = cormen_hash(X.astype(np.uint32)).astype(np.int64)
+        hY = cormen_hash(Y.astype(np.uint32)).astype(np.int64)
+        scatter_bits(m_hash, X, np.where(hX < hY, pX, pY))
+        if pos is not None:
+            posX, posY = pos[X], pos[Y]
+            scatter_bits(m_down, X, np.where(posX < posY, pX, pY))
+            scatter_bits(m_up, X, np.where(posX > posY, pX, pY))
+
+    if first_window:
+        und = t != h
+        a = np.minimum(t[und], h[und])
+        b = np.maximum(t[und], h[und])
+        ha = cormen_hash(a.astype(np.uint32)).astype(np.int64)
+        hb = cormen_hash(b.astype(np.uint32)).astype(np.int64)
+        hash_loads += np.bincount(np.where(ha < hb, parts[a], parts[b]),
+                                  minlength=P)
+        if pos is not None:
+            post, posh = pos[t], pos[h]
+            down_loads += np.bincount(pt[post < posh], minlength=P)
+            up_loads += np.bincount(pt[post > posh], minlength=P)
+            down_loads += np.bincount(ph[posh < post], minlength=P)
+            up_loads += np.bincount(ph[posh > post], minlength=P)
+    return edges_cut

@@ -376,10 +376,12 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "partition", "deduplicate parallel edges like the reference's "
        "ddup tooling"),
     _K("SHEEP_EVAL_STREAM", "flag", "auto",
-       "partition", "streamed (bounded-memory) partition evaluator; "
-       "unset = auto by size"),
-    _K("SHEEP_EVAL_STREAM_THRESHOLD", "int", "33554432",
-       "partition", "edge count above which the evaluator streams"),
+       "partition", "evaluate from .dat file blocks (bounded memory) "
+       "instead of the loaded edges; unset = auto by size"),
+    _K("SHEEP_EVAL_STREAM_THRESHOLD", "int", "134217728",
+       "partition", ".dat record count above which the evaluator reads "
+       "its edges in file blocks, not from memory; the same kernel "
+       "runs either way"),
     # -- bench / scripts (repo tooling, not the package) -------------------
     _K("SHEEP_BENCH_SIZES", "str", "",
        "bench", "bench.py size list (log2 exponents)"),

@@ -220,7 +220,7 @@ def test_streamed_evaluator_matches_inmemory(num_parts, impl):
     parts = rng.integers(0, num_parts, n).astype(np.int64)
 
     dense = evaluate_partition(parts, tail, head, seq, num_parts,
-                               max_vid=n - 1, file_edges=e)
+                               max_vid=n - 1, file_edges=e, impl="python")
     pos = sequence_positions(seq, n - 1).astype(np.int64)
 
     def blocks():
@@ -233,7 +233,102 @@ def test_streamed_evaluator_matches_inmemory(num_parts, impl):
 
     # sequence-free overload
     dense_nf = evaluate_partition(parts, tail, head, None, num_parts,
-                                  max_vid=n - 1, file_edges=e)
+                                  max_vid=n - 1, file_edges=e, impl="python")
     stream_nf = evaluate_partition_streamed(parts, blocks, None, num_parts, e,
                                             impl=impl)
     assert dense_nf == stream_nf
+
+
+def _oracle_graph(kind, num_parts):
+    """(parts, tail, head, seq, max_vid, file_edges).  "mixed": self-loops,
+    repeated records, vids without edges (INVALID_PART), and a file count
+    above the loaded records, as a partial load has.  "sparse": few
+    records over many vids, so the bitmap budget forces several passes."""
+    from sheep_tpu import INVALID_PART
+    from sheep_tpu.core.sequence import degree_sequence
+
+    rng = np.random.default_rng(7 * num_parts + len(kind))
+    n, e, used = {"mixed": (300, 1600, 250), "sparse": (5000, 600, 5000)}[kind]
+    tail = rng.integers(0, used, e).astype(np.uint32)
+    head = rng.integers(0, used, e).astype(np.uint32)
+    file_edges = e
+    if kind == "mixed":
+        head[::17] = tail[::17]                     # self-loops
+        tail = np.concatenate([tail, tail[:200]])   # repeated records
+        head = np.concatenate([head, head[:200]])
+        file_edges = len(tail) + 9
+    seq = degree_sequence(tail, head)
+    parts = np.full(n, INVALID_PART, dtype=np.int64)
+    parts[seq] = rng.integers(0, num_parts, len(seq))
+    parts[seq[-1]] = num_parts - 1                  # the highest part is used
+    return parts, tail, head, seq, n - 1, file_edges
+
+
+@pytest.mark.parametrize("kind", ["mixed", "sparse"])
+@pytest.mark.parametrize("with_seq", [True, False])
+@pytest.mark.parametrize("num_parts", [1, 2, 16, 64, 65, 100, 1024])
+def test_bitmap_evaluator_matches_sort_oracle(num_parts, with_seq, kind,
+                                              capsys):
+    # The native part-set bitmap path over the loaded arrays must give the
+    # sort body's report, field for field and in print, for every part
+    # count: one window, several windows in one pass, and several passes.
+    from sheep_tpu.partition.evaluate import _pass_words, evaluate_partition
+
+    parts, tail, head, seq, max_vid, file_edges = _oracle_graph(kind,
+                                                                num_parts)
+    seq = seq if with_seq else None
+    got, want = (evaluate_partition(parts, tail, head, seq, num_parts,
+                                    max_vid=max_vid, file_edges=file_edges,
+                                    impl=impl)
+                 for impl in ("native", "python"))
+    assert got == want
+    printed = []
+    for rep in (got, want):
+        rep.print(with_seq=with_seq)
+        printed.append(capsys.readouterr().out)
+    assert printed[0] == printed[1] and "ECV(hash)" in printed[0]
+
+    words, passes = _pass_words(len(parts), len(tail), num_parts,
+                                4 if with_seq else 2)
+    assert words * passes * 64 >= num_parts
+    if kind == "sparse" and num_parts > 64:
+        assert passes > 1
+
+
+@pytest.mark.parametrize("fault", ["short", "no_down"])
+def test_eval_block_refuses_bitmaps_of_the_wrong_size(fault):
+    """The kernel indexes vid*words + word: a bitmap sized for fewer
+    words, or a missing ECV(down) bitmap beside a position table, is
+    refused before any pointer reaches it."""
+    from sheep_tpu import native
+    if not native.available():
+        pytest.skip("native runtime not built")
+    n, words = 8, 2
+    tail = np.array([0, 1], np.uint32)
+    head = np.array([2, 3], np.uint32)
+    parts = np.zeros(n, np.int64)
+    pos = np.arange(n, dtype=np.uint32)
+    maps = [np.zeros(n * words, np.uint64) for _ in range(4)]
+    if fault == "short":
+        maps[1] = np.zeros(n, np.uint64)
+    else:
+        maps[2] = None
+    loads = [np.zeros(1, np.int64) for _ in range(3)]
+    with pytest.raises(ValueError, match="bitmaps"):
+        native.eval_block(tail, head, parts, pos, 0, words, True, *maps,
+                          np.zeros(n, np.uint8), *loads, 1)
+
+
+def test_bitmap_budget_at_graph500_scale_21():
+    """The four bitmaps of a pass fit in the sort body's doubled key
+    array: one pass up to 512 parts at 2^21 vids and 2^25 records, two at
+    1024; the sequence-free pair of bitmaps takes twice the windows."""
+    from sheep_tpu.partition.evaluate import _pass_words
+
+    n, e = 1 << 21, 1 << 25
+    assert _pass_words(n, e, 16, 4) == (1, 1)
+    assert _pass_words(n, e, 512, 4) == (8, 1)
+    assert _pass_words(n, e, 1024, 4) == (8, 2)
+    assert _pass_words(n, e, 1000, 4) == (8, 2)
+    assert _pass_words(n, e, 1024, 2) == (16, 1)
+    assert _pass_words(n, 16, 1024, 4) == (1, 16)

@@ -30,9 +30,11 @@ CHIP_PATH = {"SHEEP_WORKERS": "1", "SHEEP_STREAM_HOST_SEQ": "0",
 #: the graph2tree root's direct children on that path
 TREE_LAYERS = {"io.load", "prep", "reduce.loop", "handoff.fetch",
                "handoff.fold", "build.selfcheck", "io.write"}
-EVAL_STEPS = {False: {"evaluate.gather", "evaluate.vcom", "evaluate.ecv_hash",
-                      "evaluate.ecv_seq"},
-              True: {"evaluate.scan", "evaluate.finish"}}
+#: partition.evaluate's children: the bitmap paths (in memory and streamed)
+#: and the sort body that runs without the native runtime
+EVAL_STEPS = {"bitmap": {"evaluate.scan", "evaluate.finish"},
+              "sort": {"evaluate.gather", "evaluate.vcom", "evaluate.ecv_hash",
+                       "evaluate.ecv_seq"}}
 
 
 @pytest.fixture(autouse=True)
@@ -272,13 +274,19 @@ def test_graph2tree_root_and_its_layers(monkeypatch, graph, tmp_path):
     assert not {"loop_s", "fetch_tail_s"} & set(hand["a"])
 
 
-@pytest.mark.parametrize("streamed", [False, True])
+@pytest.mark.parametrize("streamed,kernel", [(False, "bitmap"),
+                                             (True, "bitmap"),
+                                             (False, "sort")],
+                         ids=["False", "True", "sort"])
 def test_partition_tree_evaluate_and_its_steps(monkeypatch, graph, tmp_path,
-                                               streamed):
+                                               streamed, kernel):
+    from sheep_tpu import native
     from sheep_tpu.cli import partition_tree
     _graph2tree(monkeypatch, graph)
     d, dat = graph
     monkeypatch.setenv("SHEEP_EVAL_STREAM", "1" if streamed else "0")
+    if kernel == "sort":
+        monkeypatch.setattr(native, "available", lambda: False)
     recs = _call(partition_tree.main, ["-g", dat, f"{d}/g.seq",
                                        f"{d}/g.tre", "16"],
                  str(tmp_path / "p.trace"))
@@ -288,16 +296,25 @@ def test_partition_tree_evaluate_and_its_steps(monkeypatch, graph, tmp_path,
     assert {k["a"]["kind"] for k in kids if k["name"] == "io.read"} == \
         {"seq", "tre"}
     (ev,) = [k for k in kids if k["name"] == "partition.evaluate"]
-    assert ev["a"] == {"records": 16 << 15, "keys": 32 << 15, "parts": 16,
-                       "streamed": streamed}
+    want = {"records": 16 << 15, "keys": 32 << 15, "parts": 16,
+            "streamed": streamed}
+    if not streamed:
+        want["kernel"] = kernel
+    assert ev["a"] == want
     steps = [s for s in _spans(recs) if s["par"] == ev["id"]]
-    assert {s["name"] for s in steps} == EVAL_STEPS[streamed]
+    assert {s["name"] for s in steps} == EVAL_STEPS[kernel]
+    if kernel == "bitmap":
+        (scan,) = [s for s in steps if s["name"] == "evaluate.scan"]
+        assert scan["a"] == ({"windows": 1} if streamed else
+                             {"windows": 1, "passes": 1, "source": "memory"})
     assert sum(s["dur"] for s in steps) <= ev["dur"]
     for s in steps + [ev]:
         assert 0 <= s["cpu"] <= s["dur"] + 1e-3, s
     (tree,) = [k for k in kids if k["name"] == "partition.tree"]
     ffd = [s for s in _spans(recs) if s["name"] == "native.forward_partition"]
-    assert ffd and all(s["par"] == tree["id"] for s in ffd)
+    # without the runtime the FFD runs in numpy, under no native span
+    assert bool(ffd) == (kernel == "bitmap")
+    assert all(s["par"] == tree["id"] for s in ffd)
     assert _unexplained(root, kids) <= 0.10 * root["dur"]
 
 

@@ -8,15 +8,22 @@ commands, run in this process as the user's shell would run them:
 ``graph2tree <dat> -i -r -s <seq> -o <tre>``, then, where the mix names
 part counts, ``partition_tree -g <dat> <seq> <tre> <parts...>``.
 
+The configuration's ``generator`` names ``generators/<generator>.py``,
+whose ``edges(config, seed)`` makes the graph and whose ``KEYS`` lists
+the configuration keys it reads.  A cell whose generator does not exist,
+or whose configuration sets a key that neither the harness nor its
+generator reads, is refused before any device work.
+
 Set-up (``setup_s``, from process start to the first timed job): the
-device check, the graph made on the device from ``--seed``, its ``.dat``
-written, and one warm-up ``graph2tree`` call that compiles or loads every
-program the window runs.  ``partition_tree`` runs no device program, so
-it is not warmed up: its first call is as any other.  The window is a closed loop with one client: jobs back to
-back while the elapsed time is under ``--seconds``; every job started
-completes and counts.  Then the plain reference (``plainref``) answers
-the same job, and every timed job's sequence, tree, part sizes and
-ECV(down) must equal it.
+device check, the graph made from ``--seed`` by the configuration's
+generator, its ``.dat`` written, and one warm-up ``graph2tree`` call
+that compiles or loads every program the window runs.
+``partition_tree`` runs no device program, so it is not warmed up: its
+first call is as any other.  The window is a closed loop with one
+client: jobs back to back while the elapsed time is under ``--seconds``;
+every job started completes and counts.  Then the plain reference
+(``plainref``) answers the same job, and every timed job's sequence,
+tree, part sizes and ECV(down) must equal it.
 
 ``--trace 0`` reports the cell's end-to-end metrics.  ``--trace 1`` runs
 the window under the JAX profiler and the program's flight recorder
@@ -61,9 +68,14 @@ ANNOTATIONS = ("graph2tree", "partition_tree")
 #: every compared number is an exact count of differences
 LIMITS = {"seq": 0, "parent": 0, "pst": 0, "sizes": 0, "ecv_down": 0}
 #: what a traffic mix may set: the part counts of partition_tree and its
-#: balance factor (one client, SHEEP_WORKERS=1, jobs back to back).  With
-#: no part counts a job is graph2tree alone, and has no balance factor.
+#: balance factor (one client, SHEEP_WORKERS = the cell's chips, jobs back
+#: to back).  With no part counts a job is graph2tree alone, and has no
+#: balance factor.
 TRAFFIC_KEYS = {"name", "parts", "balance_factor"}
+#: what the harness reads of a configuration itself; every other key it
+#: sets is one that its generator's ``KEYS`` lists
+CONFIG_KEYS = {"name", "source", "generator", "guarantees", "reduced",
+               "assumed"}
 
 
 class RunError(Exception):
@@ -87,6 +99,36 @@ def _json(path: str) -> dict:
         return json.load(f)
 
 
+def _load_module(kind: str, name: str):
+    """``<kind>/<name>.py`` of the benchmark, imported by path."""
+    path = os.path.join(HERE, kind, f"{name}.py")
+    spec = importlib.util.spec_from_file_location(
+        f"{kind}_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_generator(config: dict):
+    """The module of the generator the configuration names, or RunError
+    where there is none or the configuration sets a key that it and the
+    harness leave unread."""
+    name = config.get("generator")
+    have = {f[:-3] for f in os.listdir(os.path.join(HERE, "generators"))
+            if f.endswith(".py")}
+    if not isinstance(name, str) or name not in have:
+        raise RunError(f"configuration {config.get('name')!r} names the "
+                       f"generator {name!r}, which the harness does not "
+                       f"have")
+    gen = _load_module("generators", name)
+    unread = set(config) - CONFIG_KEYS - set(gen.KEYS)
+    if unread:  # a setting the harness would silently not honour
+        raise RunError(f"configuration {config.get('name')!r} sets "
+                       f"{sorted(unread)}, which neither the harness nor "
+                       f"the generator {name!r} reads")
+    return gen
+
+
 @dataclass
 class Cell:
     workload: dict
@@ -94,6 +136,7 @@ class Cell:
     traffic: dict
     e2e: list[dict]
     per_layer: list[dict]
+    generator: object  # the module of generators/<generator>.py
 
 
 def load_cell(root: str, name: str) -> Cell:
@@ -109,6 +152,7 @@ def load_cell(root: str, name: str) -> Cell:
     w = cells[name]
     cfg = next(c for c in man["configs"] if c["name"] == w["config"])
     config = _json(os.path.join(root, cfg["file"]))
+    generator = load_generator(config)
     traffic = _json(os.path.join(HERE, "traffic", f"{w['traffic']}.json"))
     unread = set(traffic) - TRAFFIC_KEYS
     if not traffic.get("parts"):
@@ -122,17 +166,12 @@ def load_cell(root: str, name: str) -> Cell:
         return name in m.get("workloads", [name])
     return Cell(w, config, traffic,
                 [m for m in man["end_to_end"] if mine(m)],
-                [m for m in man["per_layer"] if mine(m)])
+                [m for m in man["per_layer"] if mine(m)], generator)
 
 
 def load_reader(metric: str):
     """``metrics/<metric>.py``'s ``read(obs) -> float | None``."""
-    path = os.path.join(HERE, "metrics", f"{metric}.py")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + re.sub(r"\W", "_", metric), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module("metrics", metric).read
 
 
 def peaks(kind: str) -> dict:
@@ -159,6 +198,7 @@ def require_tpu(chips: int):
 @dataclass
 class Job:
     """One job's timings and what it produced."""
+    started: float = 0.0  # seconds from the window's start
     tree_s: float = 0.0
     partition_s: float = 0.0
     seq_path: str = ""
@@ -198,12 +238,14 @@ def _read_records(path: str) -> list[dict]:
 class Runner:
     """Runs jobs in this process against one graph."""
 
-    def __init__(self, dat: str, work: str, traffic: dict):
+    def __init__(self, dat: str, work: str, traffic: dict, chips: int):
         from sheep_tpu.cli import graph2tree, partition_tree
         from sheep_tpu.obs import trace as flight
         from sheep_tpu.partition.partition import Partition
         self.g2t, self.pt, self.flight = graph2tree, partition_tree, flight
         self.dat, self.work = dat, work
+        # graph2tree -i -r spans as many devices as SHEEP_WORKERS says
+        self.workers = str(chips)
         self.parts = [int(p) for p in traffic["parts"]]
         self.balance = str(traffic.get("balance_factor"))
         # the partitions partition_tree produces, in the order it prints
@@ -227,7 +269,7 @@ class Runner:
         if profile:
             import jax
             ann = jax.profiler.TraceAnnotation(annotation)
-        with _env(SHEEP_TRACE=trace, SHEEP_WORKERS="1"), \
+        with _env(SHEEP_TRACE=trace, SHEEP_WORKERS=self.workers), \
                 contextlib.redirect_stdout(out), ann:
             t = time.perf_counter()
             rc = main(argv)
@@ -379,21 +421,21 @@ def start(root: str, workload: str, devices_for) -> Session:
 
 @contextlib.contextmanager
 def graph(session: Session, seed: int):
-    """The cell's graph from ``seed``, written as a .dat into a per-run
-    directory, and a Runner for it; the directory goes at the end."""
-    cfg = session.cell.config
+    """The cell's graph from ``seed``, made by its configuration's
+    generator and written as a .dat into a per-run directory, and a
+    Runner for it; the directory goes at the end."""
+    cell = session.cell
     work = os.path.join(session.root, ".bench", "work",
-                        f"{session.cell.workload['name']}.{seed}")
+                        f"{cell.workload['name']}.{seed}")
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     runner = None
     try:
-        a, b, c = cfg["initiator"]
-        tail, head = kron.edges(cfg["scale"], cfg["edgefactor"], a, b, c,
-                                cfg["graph_seed"], seed)
+        tail, head = cell.generator.edges(cell.config, seed)
         dat = os.path.join(work, "graph.dat")
         kron.write_dat(dat, tail, head)
-        runner = Runner(dat, work, session.cell.traffic)
+        runner = Runner(dat, work, cell.traffic,
+                        int(cell.workload["chips"]))
         yield runner, tail, head
     finally:
         if runner is not None:
@@ -425,7 +467,6 @@ def _measure(args, session: Session, runner: Runner, tail, head) -> dict:
         _say(f"the warm-up graph2tree failed:\n{warm.error}")
     setup_compiles = len(compiles)
     setup_s = AGE0 + time.perf_counter() - T0
-    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
     _say(f"set-up {setup_s:.3f}s: {setup_compiles} compiles "
          f"({sum(compiles):.3f}s), warm-up graph2tree {warm.tree_s:.3f}s")
 
@@ -441,10 +482,13 @@ def _measure(args, session: Session, runner: Runner, tail, head) -> dict:
     w0 = time.perf_counter()
     while not jobs or time.perf_counter() - w0 < args.seconds:
         gc.collect()  # between the timed calls, never inside them
+        started = time.perf_counter() - w0
         jobs.append(runner.job(f"job{len(jobs)}", traced))
+        jobs[-1].started = started
     window_s = time.perf_counter() - w0
     window_compiles = len(compiles) - setup_compiles
     memory_peak = _memory_peak(devices)
+    rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
     profile = None
     if traced:
         import jax
@@ -456,7 +500,9 @@ def _measure(args, session: Session, runner: Runner, tail, head) -> dict:
           f"jobs={len(jobs)} rss_peak_bytes={rss} "
           f"memory_peak_bytes={memory_peak} "
           f"generator_peak_bytes={generator_peak}", flush=True)
-    for j in jobs:
+    for i, j in enumerate(jobs):
+        _say(f"job {i} at +{j.started:.3f}s: graph2tree {j.tree_s:.4f}s, "
+             f"partition_tree {j.partition_s:.4f}s")
         if j.error:
             _say(f"job failed:\n{j.error}")
 

@@ -74,10 +74,6 @@ def test_tree_readers(run, metric, want):
 @pytest.mark.parametrize("metric,want", [
     ("evaluate.wall_s", 40.0),
     ("evaluate.wait_s", 3.5),
-    ("evaluate.gather_s", 4.0),
-    ("evaluate.vcom_s", 6.0),
-    ("evaluate.ecv_hash_s", 16.0),
-    ("evaluate.ecv_seq_s", 13.0),
     ("untraced.partition_s", 0.1 + 0.5),
 ])
 def test_partition_readers(run, metric, want):
@@ -87,9 +83,7 @@ def test_partition_readers(run, metric, want):
 
 @pytest.mark.parametrize("metric", [
     "io.load_s", "io.write_s", "prep.ready_s", "untraced.tree_s",
-    "evaluate.wall_s", "evaluate.wait_s", "untraced.partition_s",
-    "evaluate.gather_s", "evaluate.vcom_s", "evaluate.ecv_hash_s",
-    "evaluate.ecv_seq_s"])
+    "evaluate.wall_s", "evaluate.wait_s", "untraced.partition_s"])
 def test_silent_without_their_spans(run, metric):
     """The parent's records: the old spans, no root, no new layer."""
     old = [META, _span("prep.device", 2, None, 0.0, 0.01),

@@ -1774,41 +1774,82 @@ int sheep_fennel_edges(const uint32_t* tail, const uint32_t* head, int64_t m,
   return 0;
 }
 
-// One edge block of the part-set bitmap evaluator (partition/evaluate.py;
-// reference metric definitions at lib/partition.cpp:428-521).  A pass
-// covers the parts [w0, w0 + 64*words): each bitmap holds ``words``
-// uint64 per vertex, vertex X's part p at bit (p-w0)%64 of word
-// X*words + (p-w0)/64.  Updates the caller's bitmaps / load counters in
-// place; bit-identical to the python block body.  ``pos`` may be null
-// (sequence-free overload: m_down/m_up untouched).  Returns the
-// edges_cut increment (first pass only, else 0), or -1 on an
-// out-of-range vid — the wrapper raises.
-int64_t sheep_eval_block(const uint32_t* tail, const uint32_t* head,
-                         int64_t e, const int64_t* parts, int64_t n,
-                         const uint32_t* pos, int64_t pos_len,
-                         int64_t w0, int64_t words, int32_t first_window,
-                         uint64_t* m_vcom, uint64_t* m_hash,
-                         uint64_t* m_down, uint64_t* m_up,
-                         uint8_t* deg_mask, int64_t* hash_loads,
-                         int64_t* down_loads, int64_t* up_loads,
-                         int64_t num_parts) {
+}  // extern "C": the evaluator's record loop is a C++ template
+
+// One edge block of the part-set bitmap evaluator: the block's arrays
+// and the pass it marks, shared by the serial and the threaded arm.
+struct EvalBlock {
+  const uint32_t* tail;
+  const uint32_t* head;
+  const int64_t* parts;
+  int64_t n;
+  const uint32_t* pos;  // null: the sequence-free overload
+  int64_t pos_len;
+  int64_t w0, words;
+  uint64_t span;  // 64 * words parts in this pass
+  bool first_window;
+  uint64_t *m_vcom, *m_hash, *m_down, *m_up;
+  uint8_t* deg_mask;
+  int64_t num_parts;
+};
+
+// Records [lo, hi) of an EvalBlock: both endpoints' marks, and on the
+// first pass the active flags, the cut and the loads.  kShared: other
+// threads mark the same bitmaps, so a mark reads its word first and
+// takes the atomic OR only for a missing bit (a vertex of degree d gets
+// at most one bit a part in a row from its d marks, so most of a hub's
+// marks find it set and its lines stay shared instead of bouncing
+// between cores); the loads given are then the thread's own.  Adds the
+// range's cut to ``edges_cut``.  Returns false on an out-of-range vid or
+// an invalid part, before the write it would misplace.
+template <bool kShared>
+static inline bool eval_records(const EvalBlock& b, int64_t lo, int64_t hi,
+                                int64_t* hash_loads, int64_t* down_loads,
+                                int64_t* up_loads, int64_t& edges_cut) {
   constexpr uint32_t kMult = 2654435769u;  // floor(0.5*(sqrt(5)-1)*2^32)
-  const uint64_t span = 64 * (uint64_t)words;
+  // the block's fields as locals, which no store through a bitmap or a
+  // load counter can alias, so they stay in registers
+  const uint32_t *tail = b.tail, *head = b.head, *pos = b.pos;
+  const int64_t* parts = b.parts;
+  const int64_t n = b.n, pos_len = b.pos_len, w0 = b.w0, words = b.words;
+  const int64_t num_parts = b.num_parts;
+  const uint64_t span = b.span;
+  const bool first_window = b.first_window;
+  uint64_t *m_vcom = b.m_vcom, *m_hash = b.m_hash, *m_down = b.m_down,
+           *m_up = b.m_up;
+  uint8_t* deg_mask = b.deg_mask;
   // set part p's bit in vertex X's row when p lies in this pass
   auto mark = [&](uint64_t* m, uint32_t X, int64_t p) {
     const uint64_t d = (uint64_t)(p - w0);  // negative p wraps past span
-    if (d < span) m[(size_t)X * words + d / 64] |= 1ull << (d % 64);
+    if (d >= span) return;
+    uint64_t* w = m + (size_t)X * words + d / 64;
+    const uint64_t bit = 1ull << (d % 64);
+    if constexpr (kShared) {
+      if (!(__atomic_load_n(w, __ATOMIC_RELAXED) & bit))
+        __atomic_fetch_or(w, bit, __ATOMIC_RELAXED);
+    } else {
+      *w |= bit;
+    }
   };
-  int64_t edges_cut = 0;
-  for (int64_t i = 0; i < e; ++i) {
+  auto set_active = [](uint8_t* a) {
+    if constexpr (kShared) {
+      if (!__atomic_load_n(a, __ATOMIC_RELAXED))
+        __atomic_store_n(a, (uint8_t)1, __ATOMIC_RELAXED);
+    } else {
+      *a = 1;
+    }
+  };
+  int64_t cut = 0;
+  for (int64_t i = lo; i < hi; ++i) {
     const uint32_t t = tail[i], h = head[i];
-    if (t >= (uint64_t)n || h >= (uint64_t)n) return -1;
-    if (pos && (t >= (uint64_t)pos_len || h >= (uint64_t)pos_len)) return -1;
+    if (t >= (uint64_t)n || h >= (uint64_t)n) return false;
+    if (pos && (t >= (uint64_t)pos_len || h >= (uint64_t)pos_len))
+      return false;
     const int64_t pt = parts[t], ph = parts[h];
     if (first_window) {
-      deg_mask[t] = 1;
-      deg_mask[h] = 1;
-      edges_cut += pt != ph;
+      set_active(deg_mask + t);
+      set_active(deg_mask + h);
+      cut += pt != ph;
     }
     const uint32_t ht = t * kMult, hh = h * kMult;
     const uint32_t post = pos ? pos[t] : 0, posh = pos ? pos[h] : 0;
@@ -1829,21 +1870,98 @@ int64_t sheep_eval_block(const uint32_t* tail, const uint32_t* head,
       // an INVALID_PART (-1) here would be heap corruption, and the
       // python body's np.bincount raises on it — error out the same way
       if (t != h) {
-        const uint32_t a = t < h ? t : h, b = t < h ? h : t;
-        const uint32_t ha = a * kMult, hb = b * kMult;
-        const int64_t p = ha < hb ? parts[a] : parts[b];
-        if (p < 0 || p >= num_parts) return -1;
+        const uint32_t a = t < h ? t : h, c = t < h ? h : t;
+        const uint32_t ha = a * kMult, hc = c * kMult;
+        const int64_t p = ha < hc ? parts[a] : parts[c];
+        if (p < 0 || p >= num_parts) return false;
         ++hash_loads[p];
       }
       if (pos) {
         if (pt < 0 || pt >= num_parts || ph < 0 || ph >= num_parts)
-          return -1;
+          return false;
         if (post < posh) ++down_loads[pt]; else if (post > posh) ++up_loads[pt];
         if (posh < post) ++down_loads[ph]; else if (posh > post) ++up_loads[ph];
       }
     }
   }
-  return edges_cut;
+  edges_cut += cut;
+  return true;
+}
+
+extern "C" {
+
+// One edge block of the part-set bitmap evaluator (partition/evaluate.py;
+// reference metric definitions at lib/partition.cpp:428-521).  A pass
+// covers the parts [w0, w0 + 64*words): each bitmap holds ``words``
+// uint64 per vertex, vertex X's part p at bit (p-w0)%64 of word
+// X*words + (p-w0)/64.  Updates the caller's bitmaps / load counters in
+// place; bit-identical to the python block body.  ``pos`` may be null
+// (sequence-free overload: m_down/m_up untouched).  Returns the
+// edges_cut increment (first pass only, else 0), or -1 on an
+// out-of-range vid or an invalid part — the wrapper raises.
+//
+// Threaded (T = threads_for_work(e) > 1): the records go out in chunks
+// to whichever thread is free, so one descheduled core does not hold up
+// the block, and every thread marks the caller's shared bitmaps.  No
+// per-thread bitmap copies: 4n*words*8 bytes a thread is above the 8n a
+// thread the governor prices.  The loads (3P int64, whole cache lines,
+// so no two threads write one line) and the cut are each thread's own,
+// added into the caller's after its chunks; OR and integer sums commute,
+// so every output equals the serial loop's for every T.
+int64_t sheep_eval_block(const uint32_t* tail, const uint32_t* head,
+                         int64_t e, const int64_t* parts, int64_t n,
+                         const uint32_t* pos, int64_t pos_len,
+                         int64_t w0, int64_t words, int32_t first_window,
+                         uint64_t* m_vcom, uint64_t* m_hash,
+                         uint64_t* m_down, uint64_t* m_up,
+                         uint8_t* deg_mask, int64_t* hash_loads,
+                         int64_t* down_loads, int64_t* up_loads,
+                         int64_t num_parts) {
+  const EvalBlock b{tail, head, parts, n, pos, pos_len, w0, words,
+                    64 * (uint64_t)words, first_window != 0,
+                    m_vcom, m_hash, m_down, m_up, deg_mask, num_parts};
+  tstats_reset();
+  int64_t edges_cut = 0;
+#ifdef _OPENMP
+  const int T = threads_for_work(e);
+  if (T > 1) {
+    const int64_t P = first_window ? num_parts : 0;
+    // chunks of 256 to 4096 records, at least four a thread
+    const int64_t chunk = std::max<int64_t>(
+        256, std::min<int64_t>(4096, e / (4 * (int64_t)T)));
+    bool bad = false;
+    ThreadStats* ts = &g_tstats;  // the caller's slot, as in the folds
+    ts->used = T;
+#pragma omp parallel num_threads(T) reduction(|| : bad) \
+    reduction(+ : edges_cut)
+    {
+      const int th = omp_get_thread_num();
+      const double t0 = mono_s();
+      // this thread's 3P loads, on cache lines no other thread writes
+      std::vector<int64_t> own((size_t)(3 * P + 16), 0);
+      int64_t* hl = own.data() + (64 - (uintptr_t)own.data() % 64) % 64 / 8;
+      int64_t* dl = hl + P;
+      int64_t* ul = dl + P;
+#pragma omp for schedule(dynamic, 1) nowait
+      for (int64_t lo = 0; lo < e; lo += chunk)
+        if (!bad && !eval_records<true>(b, lo, std::min(lo + chunk, e),
+                                        hl, dl, ul, edges_cut))
+          bad = true;
+      if (th < kMaxThreads) ts->busy[th] += mono_s() - t0;
+      if (!bad && P) {
+#pragma omp critical(sheep_eval_loads)
+        for (int64_t p = 0; p < P; ++p) {
+          hash_loads[p] += hl[p];
+          down_loads[p] += dl[p];
+          up_loads[p] += ul[p];
+        }
+      }
+    }
+    return bad ? -1 : edges_cut;
+  }
+#endif
+  return eval_records<false>(b, 0, e, hash_loads, down_loads, up_loads,
+                             edges_cut) ? edges_cut : -1;
 }
 
 // ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ..obs import trace as obs
+from ..resources.governor import native_thread_plan, native_threads_env
 
 _CORMEN_MULT = np.uint64(2654435769)  # floor(0.5*(sqrt(5)-1) * 2^32)
 
@@ -292,7 +293,13 @@ def _bitmap_eval(parts, P, blocks_factory, pos, num_parts, file_edges,
     # distinct (vertex, part) pairs of Vcom, ECV(hash), ECV(down), ECV(up)
     pairs = [0] * len(bitmaps)
 
-    with obs.span("evaluate.scan", windows=-(-P // 64), **scan_attrs):
+    # the scan's blocks run on the build's thread plan; the span's
+    # ``threads`` is what the kernel's last call really used.  The plan
+    # prices the build's 8n-byte partial tables a thread, which this scan
+    # never allocates (its own is 24P bytes a thread), so a tight memory
+    # budget can cut the scan's threads further than the scan needs
+    with obs.span("evaluate.scan", windows=-(-P // 64), **scan_attrs) as sp, \
+            native_threads_env(native_thread_plan(n)):
         for w0 in range(0, P, width):
             if w0:
                 for m in bitmaps:
@@ -311,6 +318,8 @@ def _bitmap_eval(parts, P, blocks_factory, pos, num_parts, file_edges,
                 np.uint64(1) << (d % 64).astype(np.uint64)
             for i, m in enumerate(bitmaps):
                 pairs[i] += int(np.bitwise_count(m).sum())
+        if native is not None:
+            native._annotate_threads(sp)
 
     with obs.span("evaluate.finish"):
         active = np.nonzero(deg_mask)[0]

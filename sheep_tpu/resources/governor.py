@@ -34,6 +34,7 @@ dispatch boundary.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass
 
@@ -415,6 +416,29 @@ def native_thread_plan(n: int, governor: "ResourceGovernor | None" = None
             "leg_cores": leg_cores or None,
             "partial_bytes": native_thread_tables_nbytes(n, t),
             "reason": reason}
+
+
+@contextlib.contextmanager
+def native_threads_env(tplan: dict):
+    """Export the governor's resolved thread count as
+    ``SHEEP_NATIVE_THREADS`` for the duration of one rung attempt or one
+    evaluator scan (the kernels read the env per call), restoring the
+    previous value on any exit — one call must never re-pin the whole
+    process.  A pinned env (``forced``) is the operator's word and is
+    left alone."""
+    if tplan["forced"] or (tplan["threads"] <= 1
+                           and NATIVE_THREADS_ENV not in os.environ):
+        yield
+        return
+    prev = os.environ.get(NATIVE_THREADS_ENV)
+    os.environ[NATIVE_THREADS_ENV] = str(tplan["threads"])
+    try:
+        yield
+    finally:
+        if prev is None:
+            os.environ.pop(NATIVE_THREADS_ENV, None)
+        else:
+            os.environ[NATIVE_THREADS_ENV] = prev
 
 
 @dataclass

@@ -35,7 +35,6 @@ pst, and ECV(down) against the uninterrupted build.
 
 from __future__ import annotations
 
-import contextlib
 import os
 from dataclasses import dataclass, field
 
@@ -50,7 +49,7 @@ from ..obs import trace as obs
 from ..plan.model import DEFAULT_LADDER, PROV_LEARNED, available_rungs, \
     plan_build
 from ..resources.errors import MemoryBudgetExceeded, ResourceError
-from ..resources.governor import (NATIVE_THREADS_ENV, ResourceGovernor,
+from ..resources.governor import (ResourceGovernor, native_threads_env,
                                   rss_bytes)
 from .faults import (RetryBudgetExhausted, fault_point, is_retryable,
                      reset_counters)
@@ -472,28 +471,6 @@ _RUNGS = {"mesh": _rung_mesh, "single": _rung_single, "host": _rung_host,
           "stream": _rung_stream, "ext": _rung_ext, "spill": _rung_spill}
 
 
-@contextlib.contextmanager
-def _native_threads_env(tplan: dict):
-    """Export the governor's resolved thread count as
-    ``SHEEP_NATIVE_THREADS`` for the duration of one rung attempt (the
-    kernels read the env per call), restoring the previous value on any
-    exit — one driver call must never re-pin the whole process.  A
-    pinned env (``forced``) is the operator's word and is left alone."""
-    if tplan["forced"] or (tplan["threads"] <= 1
-                           and NATIVE_THREADS_ENV not in os.environ):
-        yield
-        return
-    prev = os.environ.get(NATIVE_THREADS_ENV)
-    os.environ[NATIVE_THREADS_ENV] = str(tplan["threads"])
-    try:
-        yield
-    finally:
-        if prev is None:
-            os.environ.pop(NATIVE_THREADS_ENV, None)
-        else:
-            os.environ[NATIVE_THREADS_ENV] = prev
-
-
 def _ladder_rungs(config: RuntimeConfig, num_workers) -> list[str]:
     # availability routes through the planner (ISSUE 15): one filter for
     # the driver, the plan CLI, and anything else that must answer
@@ -646,7 +623,7 @@ def build_graph_resilient(tail, head, num_vertices=None, num_workers=None,
             rt.boundary(0, lambda: (lo, hi))
         try:
             with obs.span("rung", rung=rung, links=len(lo)), \
-                    _native_threads_env(tplan):
+                    native_threads_env(tplan):
                 parent = _RUNGS[rung](lo, hi, n, rt, num_workers)
             obs.event("rung.ok", rung=rung, rss_bytes=rss_bytes(),
                       est_bytes=price_of.get(rung), n=n)

@@ -124,6 +124,78 @@ def test_threaded_histogram_rejects_bad_vid(thread_env):
                                 np.repeat(head, 400), 50)
 
 
+def _eval_graph():
+    """(tail, head, parts, pos): a hub (vid 0 on a third of the records),
+    self-loops and repeated records over 400 vids, parts over three
+    64-part windows."""
+    rng = np.random.default_rng(11)
+    n, e = 400, 3000
+    tail = rng.integers(0, n, e).astype(np.uint32)
+    head = rng.integers(0, n, e).astype(np.uint32)
+    tail[::3] = 0                                   # the hub
+    head[::29] = tail[::29]                         # self-loops
+    tail = np.concatenate([tail, tail[:500]])       # repeated records
+    head = np.concatenate([head, head[:500]])
+    parts = rng.integers(0, 3 * 64, n).astype(np.int64)
+    return tail, head, parts, rng.permutation(n).astype(np.uint32)
+
+
+def _eval_block(block, tail, head, parts, pos, w0, words):
+    """One first-window block through ``block`` on fresh state; returns
+    the cut and the state arrays it updated."""
+    n, P = len(parts), int(parts.max()) + 1
+    maps = [np.zeros(n * words, np.uint64) for _ in range(4)]
+    if pos is None:
+        maps[2:] = [None, None]
+    state = maps + [np.zeros(n, np.uint8)] + \
+        [np.zeros(P, np.int64) for _ in range(3)]
+    cut = block(tail, head, parts, pos, w0, words, True, *state, P)
+    return cut, [s for s in state if s is not None]
+
+
+@needs_omp
+@pytest.mark.parametrize("w0", [0, 64])
+@pytest.mark.parametrize("words", [1, 2])
+@pytest.mark.parametrize("with_seq", [True, False])
+@pytest.mark.parametrize("threads", [1, 2, 4])
+def test_eval_block_bit_identical_across_thread_counts(thread_env, threads,
+                                                       with_seq, words, w0):
+    """The bitmap scan's threaded arm ORs into shared bitmaps and sums
+    per-thread loads: bitmaps, deg_mask, the three load arrays and the cut
+    equal the serial kernel's and the numpy body's for every T."""
+    from sheep_tpu.partition.evaluate import _eval_block_python
+    tail, head, parts, pos = _eval_graph()
+    args = (tail, head, parts, pos if with_seq else None, w0, words)
+    want = _eval_block(_eval_block_python, *args)
+    thread_env.setenv("SHEEP_NATIVE_THREADS", "1")
+    serial = _eval_block(native.eval_block, *args)
+    thread_env.setenv("SHEEP_NATIVE_THREADS", str(threads))
+    assert native.threads_for(len(tail)) == threads
+    got = _eval_block(native.eval_block, *args)
+    for ref in (serial, want):
+        assert got[0] == ref[0]
+        assert len(got[1]) == len(ref[1])
+        for a, b in zip(got[1], ref[1]):
+            np.testing.assert_array_equal(a, b)
+
+
+@needs_omp
+@pytest.mark.parametrize("fault", ["vid", "part"])
+def test_threaded_eval_block_rejects_bad_input(thread_env, fault):
+    """An out-of-range vid, or a streamed vertex with INVALID_PART, makes
+    one slice of the threaded scan fail, and the call raises."""
+    from sheep_tpu import INVALID_PART
+    thread_env.setenv("SHEEP_NATIVE_THREADS", "4")
+    tail, head, parts, pos = _eval_graph()
+    if fault == "vid":
+        tail[len(tail) // 2] = len(parts)
+    else:
+        parts[tail[len(tail) // 2]] = INVALID_PART
+    assert native.threads_for(len(tail)) == 4
+    with pytest.raises(ValueError, match="out of range"):
+        _eval_block(native.eval_block, tail, head, parts, pos, 0, 1)
+
+
 @needs_omp
 def test_resumable_fold_threaded_matches_pylinksfold(thread_env):
     """The windowed resumable fold under forced threads equals the

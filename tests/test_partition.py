@@ -332,3 +332,57 @@ def test_bitmap_budget_at_graph500_scale_21():
     assert _pass_words(n, e, 1000, 4) == (8, 2)
     assert _pass_words(n, e, 1024, 2) == (16, 1)
     assert _pass_words(n, 16, 1024, 4) == (1, 16)
+
+
+@pytest.fixture
+def scan_threads(monkeypatch):
+    """The native runtime with OpenMP, the threaded arm engaged on
+    test-sized inputs and allowed past this host's cores, and a host of
+    four effective cores for the thread plan."""
+    from sheep_tpu import native
+    import sheep_tpu.utils.envinfo as envinfo
+    if not (native.available() and native.omp_compiled()):
+        pytest.skip("native runtime with OpenMP not built")
+    monkeypatch.setenv("SHEEP_NATIVE_THREAD_FLOOR", "0")
+    monkeypatch.setenv("SHEEP_NATIVE_OVERSUB", "1")
+    for k in ("SHEEP_NATIVE_THREADS", "SHEEP_MEM_BUDGET", "SHEEP_LEG_CORES"):
+        monkeypatch.delenv(k, raising=False)
+    monkeypatch.setattr(envinfo, "effective_cores", lambda root=None: 4)
+    return monkeypatch
+
+
+def test_evaluator_reports_equal_at_one_and_four_threads(scan_threads):
+    parts, tail, head, seq, max_vid, file_edges = _oracle_graph("mixed", 16)
+    reps = []
+    for threads in ("1", "4"):
+        scan_threads.setenv("SHEEP_NATIVE_THREADS", threads)
+        reps.append(evaluate_partition(parts, tail, head, seq, 16,
+                                       max_vid=max_vid, file_edges=file_edges,
+                                       impl="native"))
+    assert reps[0] == reps[1]
+
+
+@pytest.mark.parametrize("pin", [None, "2"])
+def test_evaluator_scan_runs_on_the_thread_plan(scan_threads, tmp_path, pin):
+    """Unpinned, the scan runs on the plan's four threads and leaves
+    SHEEP_NATIVE_THREADS unset; an operator's pin wins and stays set.
+    The ``evaluate.scan`` span says what the kernel used."""
+    import os
+    from sheep_tpu.obs import trace as obs_trace
+    if pin is not None:
+        scan_threads.setenv("SHEEP_NATIVE_THREADS", pin)
+    parts, tail, head, seq, max_vid, file_edges = _oracle_graph("mixed", 16)
+    tpath = str(tmp_path / "eval.trace")
+    scan_threads.setenv(obs_trace.ENV, tpath)
+    try:
+        evaluate_partition(parts, tail, head, seq, 16, max_vid=max_vid,
+                           file_edges=file_edges, impl="native")
+    finally:
+        obs_trace.close_recorder()
+    assert os.environ.get("SHEEP_NATIVE_THREADS") == pin
+    records, _, _ = obs_trace.read_trace(tpath, "strict")
+    (scan,) = [r["a"] for r in records if r.get("k") == "span"
+               and r.get("name") == "evaluate.scan"]
+    want = 4 if pin is None else int(pin)
+    assert scan["threads"] == want
+    assert len(scan["thread_busy_s"]) == want

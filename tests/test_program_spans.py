@@ -305,8 +305,11 @@ def test_partition_tree_evaluate_and_its_steps(monkeypatch, graph, tmp_path,
     assert {s["name"] for s in steps} == EVAL_STEPS[kernel]
     if kernel == "bitmap":
         (scan,) = [s for s in steps if s["name"] == "evaluate.scan"]
-        assert scan["a"] == ({"windows": 1} if streamed else
-                             {"windows": 1, "passes": 1, "source": "memory"})
+        attrs = dict(scan["a"])
+        threads = attrs.pop("threads")
+        assert len(attrs.pop("thread_busy_s", [0])) == threads >= 1
+        assert attrs == ({"windows": 1} if streamed else
+                         {"windows": 1, "passes": 1, "source": "memory"})
     assert sum(s["dur"] for s in steps) <= ev["dur"]
     for s in steps + [ev]:
         assert 0 <= s["cpu"] <= s["dur"] + 1e-3, s

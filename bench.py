@@ -228,20 +228,18 @@ def _run_one(log_n: int) -> dict:
         # overlap/pipeline observability for on-chip interpretation: the
         # best rep's reduce+tail breakdown — the streaming windowed
         # handoff's per-window fetch/fold timers and overlap fraction
-        # (reduce_and_finish_native), plus the legacy speculation
-        # counters when the serial path ran
+        # (reduce_and_finish_native)
         best_perf = perfs[times.index(best)]
         if best_perf:
             rec[name]["perf"] = {k: v for k, v in best_perf.items()
                                  if k in ("loop_s", "fetch_tail_s",
-                                          "overlap", "stream_mode",
+                                          "stream_mode",
                                           "fetch_windows", "fold_s",
                                           "window_fetch_s",
                                           "window_fold_s", "overlap_s",
                                           "overlap_frac",
                                           "handoff_links",
-                                          "packed_handoff")
-                                 or k.startswith("spec_")}
+                                          "packed_handoff")}
         if name == "device":
             rec[name]["rounds"] = int(out[1])
         if name == "hybrid":

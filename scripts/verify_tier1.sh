@@ -263,7 +263,7 @@ fi
 # forced on cpu, and the resumable fold — each bit-identical to the
 # oracle.  Seconds of work; a regression in the round-7 streaming tail
 # fails the gate before pytest even runs.
-if ! env JAX_PLATFORMS=cpu SHEEP_STREAM_HANDOFF=1 SHEEP_HANDOFF_WINDOWS=4 \
+if ! env JAX_PLATFORMS=cpu SHEEP_HANDOFF_WINDOWS=4 \
      python - <<'EOF'
 import os
 import numpy as np

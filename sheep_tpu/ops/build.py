@@ -17,7 +17,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import INVALID_JNID
 from ..core.forest import Forest
 from ..obs import trace as obs
 from .forest import forest_fixpoint, pst_weights
@@ -140,18 +139,16 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
     connectivity, and the elimination forest is a function of threshold
     connectivity only (module docstring of ops.forest).
 
-    The handoff itself is the STREAMING WINDOWED tail by default (round
-    7, :func:`stream_handoff_enabled` / SHEEP_STREAM_HANDOFF): the
-    reduced live set fetches as W ascending hi-quantile windows
+    The handoff is the streaming windowed tail (:func:`_stream_tail`):
+    the reduced live set fetches as W ascending hi-quantile windows
     (SHEEP_HANDOFF_WINDOWS; shared quantile rule with the mesh tail
-    shard), each folded through the RESUMABLE native union-find
+    shard), each folded through the resumable native union-find
     (native.LinksFold) the moment it lands — fold k overlaps fetch k+1
     and the full link table never materializes host-side.  On the cpu
-    backend the fetch is a zero-copy view, so the stream instead drops
+    backend the fetch is a zero-copy view, so the tail instead drops
     the pre-fold device sort and (host_seq_mode) moves the degree
     sequence to the native counting sort, shrinking the device program
-    to the link mapping.  A failed fetch or fold raises: no path falls
-    back in silence.
+    to the link mapping.  A failed fetch or fold raises.
 
     Returns (seq uint32 [m], Forest over m), bit-identical to the oracle.
 
@@ -169,12 +166,11 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
     way, but 2n*4B less d2h traffic.  Numpy tail/head inputs serve as
     their own host copy automatically.
 
-    ``perf`` — optional dict receiving the reduce+fetch breakdown and
-    speculation counters (loop_s / fetch_tail_s / overlap / spec_* —
-    see reduce_and_fetch_links), for bench/profile observability.  The
-    device rounds, the live links at the handoff and the handoff's mode
-    land as one ``hybrid.handoff`` trace event; its times are the
-    ``reduce.loop`` and ``handoff.*`` spans.
+    ``perf`` — optional dict receiving the reduce + tail breakdown (see
+    :func:`reduce_and_finish_native`).  The device rounds, the live
+    links at the handoff and the handoff's mode land as one
+    ``hybrid.handoff`` trace event; its times are the ``reduce.loop``
+    and ``handoff.*`` spans.
 
     ``seq`` — an externally given elimination order (the `-s`/`-r` case):
     skips the device degree histogram + sort entirely (two fewer full-E
@@ -198,20 +194,18 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
     if host_edges is None \
             and isinstance(tail, np.ndarray) and isinstance(head, np.ndarray) \
             and (jax.devices()[0].platform != "cpu"
-                 or (stream_handoff_enabled() and handoff_input_ok())):
+                 or handoff_input_ok()):
         # auto-detect where the host copy buys something real: on
         # accelerators it saves the 2n*4B seq/pst d2h; on the cpu
-        # backend it used to be off (the host recompute competed with
-        # the reduce loop for the same cores), but under the streaming
-        # immediate handoff there IS no reduce loop — the copy instead
-        # enables the host-seq prep below
+        # backend under the immediate handoff there is no reduce loop
+        # to compete with, and the copy enables the host-seq prep below
         host_edges = (tail, head)
     given_seq = None
     _lazy_pst = None
     acc_ok = False  # may the tail fold count pst from its own stream?
     if seq is None and host_edges is not None and host_seq_mode() \
-            and stream_handoff_enabled() and handoff_input_ok():
-        # streaming cpu prep (round 7): the native counting-sort degree
+            and handoff_input_ok():
+        # immediate-handoff cpu prep: the native counting-sort degree
         # sequence (~6x the XLA histogram+sort on the same silicon)
         # computed host-side UP FRONT, so the device program shrinks to
         # the link mapping alone.  Bit-identical: the host sequence
@@ -255,8 +249,7 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
         with obs.span("prep", n=n) as sp:
             dev_seq, _, m, lo, hi, pst = prepare_links(
                 jnp.asarray(tail), jnp.asarray(head), n,
-                with_pst=host_edges is None
-                and not (stream_handoff_enabled() and handoff_input_ok()))
+                with_pst=host_edges is None and not handoff_input_ok())
             sp.end_when_ready(m)
         # full-graph prep: every vid holds a position, so the link
         # multiset carries no maskable pst-only records — the streaming
@@ -324,11 +317,7 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
         return _as_u32(np.asarray(_pst_resolved()))
 
     # immediate-handoff only where its trade was measured to win — the
-    # shared handoff_input_ok gate (same for the stream's final fold and
-    # the profiler, so the sites can't drift).  The tail is the shared
-    # production reduce+finish: the streaming windowed handoff (fold of
-    # window k overlapping fetch of window k+1) when enabled, the serial
-    # fetch + monolithic fold otherwise — bit-identical either way.
+    # handoff_input_ok gate shared with ops.stream's final fold
     if perf is None:
         perf = {}
     res = reduce_and_finish_native(
@@ -354,12 +343,12 @@ def build_graph_hybrid(tail: np.ndarray, head: np.ndarray,
 
 
 def handoff_input_ok() -> bool:
-    """THE immediate-handoff gate, shared by every caller (the hybrid,
-    the streaming final fold, scripts/hybrid_profile) so the sites can't
-    drift: skip the device dedupe rounds only where the d2h copy is free
-    (cpu backend) AND the native union-find consumes the undeduped links
-    (the pure-python UF pays per link; a byte-bound accelerator fetch
-    wants the dedupe rounds to shrink the volume first)."""
+    """THE immediate-handoff gate, shared by the hybrid and the
+    streaming final fold so the sites can't drift: skip the device
+    dedupe rounds only where the d2h copy is free (cpu backend) AND the
+    native union-find consumes the undeduped links (the pure-python UF
+    pays per link; a byte-bound accelerator fetch wants the dedupe
+    rounds to shrink the volume first)."""
     from ..core.forest import native_or_none
     return jax.devices()[0].platform == "cpu" \
         and native_or_none("auto") is not None
@@ -386,38 +375,14 @@ def default_handoff_factor() -> int:
 
 
 def pack_handoff(n: int) -> bool:
-    """THE 6-byte-packing policy, shared by the serial fetch
-    (fetch_links_host) and the overlapped stream (_StreamFetcher) so
-    SHEEP_PACK_HANDOFF means ONE thing across both paths (ADVICE r05:
-    the stream used to pack on n alone, so a pack-off A/B arm with
-    overlap on still packed).  Default: pack on accelerators, where the
-    fetch is a real transfer, not on cpu; packing needs n < 2^24.
+    """6-byte packing of the window stream's slices (SHEEP_PACK_HANDOFF
+    overrides).  Default: pack on accelerators, where the fetch is a
+    real transfer, not on cpu; packing needs n < 2^24.
     """
     pack = os.environ.get("SHEEP_PACK_HANDOFF", "")
     if pack == "":
         pack = "0" if jax.devices()[0].platform == "cpu" else "1"
     return pack == "1" and n < (1 << 24)
-
-
-def fetch_links_host(lo, hi, live: int, n: int):
-    """THE production link-fetch policy, shared with scripts/hybrid_profile
-    so the profiler's d2h phase can never drift from what the hybrid
-    actually does: 64K-granular cut (each distinct slice length is a fresh
-    XLA program), 6-byte packing where the fetch is a real transfer
-    (:func:`pack_handoff`), dead-sentinel filter.
-    Returns (lo_h, hi_h uint-safe int arrays, packed: bool).
-    """
-    cut = min(int(lo.shape[0]), -(-live // (1 << 16)) * (1 << 16))
-    packed = pack_handoff(n)
-    if packed:
-        from .forest import pack_links_6b, unpack_links_6b
-        buf = np.asarray(pack_links_6b(lo[:cut], hi[:cut]))[:live]
-        lo_h, hi_h = unpack_links_6b(buf)
-    else:
-        lo_h = np.asarray(lo[:cut])[:live]
-        hi_h = np.asarray(hi[:cut])[:live]
-    keep = lo_h < n  # a few scattered dead slots may remain in the prefix
-    return lo_h[keep], hi_h[keep], packed
 
 
 @functools.partial(jax.jit, static_argnames=("length",))
@@ -428,34 +393,29 @@ def _slice_rows(buf, start, length: int):
     return jax.lax.dynamic_slice_in_dim(buf, start, length, 0)
 
 
-def _overlap_enabled() -> bool:
-    """Overlapped speculative handoff gate (SHEEP_OVERLAP_HANDOFF
-    overrides): default ON for accelerators — where the link d2h is a
-    real transfer worth hiding behind device rounds — and OFF on the cpu
-    backend, where the fetch is a near-free copy and the immediate-
-    handoff path already skips rounds entirely."""
-    v = os.environ.get("SHEEP_OVERLAP_HANDOFF", "")
-    if v != "":
-        return v == "1"
-    return jax.devices()[0].platform != "cpu"
+class _WindowStream:
+    """Background slice-streamed d2h of a hi-sorted link table, handed
+    out in windows (the streaming windowed handoff's transfer side).
 
-
-class _StreamFetcher:
-    """Background slice-streamed d2h of one link snapshot.
-
-    The snapshot (lo, hi) is an immutable device-array pair with the
-    live-prefix guarantee (all live links in the first ``live`` slots),
-    so fetching it concurrently with later chunk dispatches is safe.
-    Transfers run as fixed-length slices of a 6-byte-packed buffer
-    (n < 2^24; int32 pairs otherwise) so progress is observable between
-    slices and an abort loses at most one slice of link time.
+    The table (lo, hi) is an immutable device-array pair, sorted by hi,
+    with every live link in the first ``live`` slots.  It transfers as
+    fixed-length slices of a 6-byte-packed buffer (:func:`pack_handoff`;
+    int32 pairs otherwise), grouped into W equal-count windows —
+    contiguous count-slices of the sorted table ARE the hi-quantile
+    windows (parallel.chunked.hi_window_bounds rule).  The fetch thread
+    runs at most :data:`PREFETCH` windows ahead of the fold consumer, so
+    resident host memory is O(live/W * PREFETCH), never the full table;
+    :meth:`window` hands window k to the fold and frees its slices while
+    k+1 keeps streaming underneath.
     """
 
+    #: windows allowed in flight beyond the one being folded (double
+    #: buffering: fold k while k+1 lands and k+2 streams)
+    PREFETCH = 2
+
     def __init__(self, lo, hi, n: int, live: int, slice_links: int,
-                 autostart: bool = True):
-        self.n = n
-        self.live = live
-        self.packed = pack_handoff(n)  # ONE policy with fetch_links_host
+                 windows: int):
+        self.packed = pack_handoff(n)
         self.bytes_per_link = 6 if self.packed else 8
         width = int(lo.shape[0])  # pow2-padded
         # the env knob is an arbitrary int: round DOWN to a power of two
@@ -467,7 +427,7 @@ class _StreamFetcher:
                                 width // self.slice_len)
         self.done_slices = 0
         self.failed = False
-        #: the exception that stopped the fetch thread; owners re-raise it
+        #: the exception that stopped the fetch thread; window() re-raises it
         self.error: BaseException | None = None
         #: per-slice fetch seconds (obs.trace.timed — the one timing
         #: path); ``busy_s`` below is the derived view
@@ -481,24 +441,35 @@ class _StreamFetcher:
             self._dev = pack_links_6b(lo, hi)
         else:
             self._dev = (lo.astype(jnp.int32), hi.astype(jnp.int32))
+        self._cv = threading.Condition()
+        self._consumed = -1  # highest window already handed to the fold
+        w = max(1, min(windows, self.total_slices))
+        self.windows = w
+        self._cuts = [(k * self.total_slices) // w for k in range(w + 1)]
         self._thread = threading.Thread(target=obs.bind(self._run),
                                         daemon=True)
-        if autostart:
-            self._thread.start()
-
-    # subclass seams (the window-queue stream, _WindowStream): gate a
-    # slice before its fetch, observe one landing.  Base: free-running.
-    def _wait_turn(self, i: int) -> None:
-        pass
-
-    def _on_slice(self) -> None:
-        pass
+        self._thread.start()
 
     @property
     def busy_s(self) -> float:
         """Thread time actually spent fetching slices (the overlap
         accounting's ``serialized`` fetch term)."""
         return sum(self._slice_s)
+
+    def _window_of(self, i: int) -> int:
+        import bisect
+        return bisect.bisect_right(self._cuts, i) - 1
+
+    def _wait_turn(self, i: int) -> None:
+        with self._cv:
+            while (not self._abort
+                   and self._window_of(i)
+                   > self._consumed + 1 + self.PREFETCH):
+                self._cv.wait(0.5)
+
+    def _notify(self) -> None:
+        with self._cv:
+            self._cv.notify_all()
 
     def _run(self) -> None:
         try:
@@ -520,100 +491,13 @@ class _StreamFetcher:
                              np.asarray(_slice_rows(hi_d, start,
                                                     self.slice_len))))
                 self.done_slices = i + 1
-                self._on_slice()
-        except Exception as exc:  # re-raised by the stream's owner
+                self._notify()
+        except Exception as exc:  # re-raised by window()
             self.error = exc
             self.failed = True
         finally:
             self._dev = None  # release the device buffer promptly
-            self._on_slice()
-
-    def finished(self) -> bool:
-        return not self.failed and self.done_slices >= self.total_slices
-
-    def remaining_bytes(self) -> int:
-        return (self.total_slices - self.done_slices) * self.slice_len \
-            * self.bytes_per_link
-
-    def join(self, timeout: float | None = None,
-             mark_failed: bool = True) -> bool:
-        """Wait for the stream; True if it is STILL RUNNING afterwards.
-        A wedged transfer must never block the build forever —
-        ``mark_failed`` callers treat a timed-out join as failed and
-        fall back to the serial fetch, which they record.  The daemon
-        thread is left behind; slice appends are atomic, so a later
-        collect() snapshot stays consistent."""
-        self._thread.join(timeout)
-        alive = self._thread.is_alive()
-        if alive and mark_failed:
-            self.failed = True
-        return alive
-
-    def abort(self, timeout: float = 5.0) -> None:
-        """Stop at the next slice boundary; wait only briefly.  A
-        slow-but-healthy in-flight slice (queued behind pipelined chunk
-        dispatches) must NOT poison the fetcher as failed — the caller
-        keeps whatever slices have landed and the thread drains itself
-        within one slice; mark_failed=False so only a real _run
-        exception disables later speculation."""
-        self._abort = True
-        self.join(timeout, mark_failed=False)
-
-    def fetched_bytes(self) -> int:
-        return self.done_slices * self.slice_len * self.bytes_per_link
-
-    def collect(self) -> tuple[np.ndarray, np.ndarray]:
-        """Host (lo, hi) of every fetched slice (unfiltered — dead
-        sentinel slots remain; callers mask lo < n)."""
-        if not self._slices:
-            return (np.empty(0, np.int32), np.empty(0, np.int32))
-        if self.packed:
-            from .forest import unpack_links_6b
-            return unpack_links_6b(np.concatenate(self._slices))
-        los, his = zip(*self._slices)
-        return np.concatenate(los), np.concatenate(his)
-
-
-class _WindowStream(_StreamFetcher):
-    """Window-queue generalization of the snapshot stream (the streaming
-    windowed handoff's transfer side): a hi-SORTED device link table
-    streams as fixed-length slices grouped into W equal-count windows —
-    contiguous count-slices of the sorted table ARE the hi-quantile
-    windows (parallel.chunked.hi_window_bounds rule) — and the fetch
-    thread runs at most :data:`PREFETCH` windows ahead of the fold
-    consumer.  Resident host memory is therefore O(live/W * PREFETCH),
-    never the full table; :meth:`window` hands window k to the fold and
-    frees its slices while k+1 keeps streaming underneath.
-    """
-
-    #: windows allowed in flight beyond the one being folded (double
-    #: buffering: fold k while k+1 lands and k+2 streams)
-    PREFETCH = 2
-
-    def __init__(self, lo, hi, n: int, live: int, slice_links: int,
-                 windows: int):
-        super().__init__(lo, hi, n, live, slice_links, autostart=False)
-        self._cv = threading.Condition()
-        self._consumed = -1  # highest window already handed to the fold
-        w = max(1, min(windows, self.total_slices))
-        self.windows = w
-        self._cuts = [(k * self.total_slices) // w for k in range(w + 1)]
-        self._thread.start()
-
-    def _window_of(self, i: int) -> int:
-        import bisect
-        return bisect.bisect_right(self._cuts, i) - 1
-
-    def _wait_turn(self, i: int) -> None:
-        with self._cv:
-            while (not self._abort
-                   and self._window_of(i)
-                   > self._consumed + 1 + self.PREFETCH):
-                self._cv.wait(0.5)
-
-    def _on_slice(self) -> None:
-        with self._cv:
-            self._cv.notify_all()
+            self._notify()
 
     def window(self, k: int, timeout_s: float | None = None):
         """Block until window k has fully landed, then return its host
@@ -630,8 +514,8 @@ class _WindowStream(_StreamFetcher):
     def collect_range(self, s0: int, s1: int,
                       timeout_s: float | None = None):
         if timeout_s is None:
-            # generous watchdog, same spirit as _SpecHandoff.complete: a
-            # wedged transfer must never hold the build forever
+            # generous watchdog (the range's bytes at 0.5MB/s plus
+            # grace): a wedged transfer must never hold the build forever
             timeout_s = ((s1 - s0) * self.slice_len * self.bytes_per_link
                          / 5e5 + 120.0)
         deadline = time.monotonic() + timeout_s
@@ -660,30 +544,11 @@ class _WindowStream(_StreamFetcher):
         return np.concatenate(los), np.concatenate(his)
 
     def abort(self, timeout: float = 5.0) -> None:
+        """Stop at the next slice boundary and wait briefly for the
+        thread; the landed slices stay a prefix of the table."""
         self._abort = True
-        with self._cv:
-            self._cv.notify_all()
-        self.join(timeout, mark_failed=False)
-
-
-def stream_handoff_enabled() -> bool:
-    """THE streaming-windowed-handoff gate (SHEEP_STREAM_HANDOFF
-    overrides; default on): the hybrid's tail consumes the reduced live
-    set as ascending hi-quantile windows, each folded through the
-    resumable native union-find (native.LinksFold — python twin without
-    the runtime) the moment it lands, so the fold of window k overlaps
-    the fetch of window k+1 and the full tail link table never
-    materializes host-side.  Any stream failure falls back to the serial
-    fetch mid-build, exactly like _SpecHandoff's failure path."""
-    v = os.environ.get("SHEEP_STREAM_HANDOFF", "")
-    if v != "":
-        return v == "1"
-    # an EXPLICIT legacy-overlap arm (SHEEP_OVERLAP_HANDOFF=1) keeps the
-    # speculative-snapshot path unless the stream is explicitly chosen
-    # too, so the round-4/5 A/B arms keep measuring what they name
-    if os.environ.get("SHEEP_OVERLAP_HANDOFF", "") == "1":
-        return False
-    return True
+        self._notify()
+        self._thread.join(timeout)
 
 
 def handoff_windows(live: int) -> int:
@@ -751,9 +616,8 @@ def _stream_tail(lo, hi, live: int, n: int, pst_h, accumulate: bool,
     w = handoff_windows(int(live))
     platform = jax.devices()[0].platform
     # SHEEP_STREAM_DEVICE_WINDOWS=1 forces the accelerator transfer path
-    # (device hi-sort + _WindowStream slices) on the cpu backend — the
-    # same trick the overlap tests use, so the window-queue machinery is
-    # exercised without hardware
+    # (device hi-sort + _WindowStream slices) on the cpu backend, so the
+    # window-queue machinery is exercised without hardware
     device_windows = platform != "cpu" \
         or os.environ.get("SHEEP_STREAM_DEVICE_WINDOWS", "") == "1"
     stream = None
@@ -850,230 +714,16 @@ def _sort_by_hi_prog(lo, hi):
     return sort_links_by_hi(lo, hi)
 
 
-class _SpecHandoff:
-    """Speculative overlapped handoff policy (VERDICT r04 item 1).
-
-    Soundness: every chunk output has the same threshold connectivity as
-    the input links (ops.forest module proof), the elimination forest is
-    a function of threshold connectivity only, and the native union-find
-    accepts an arbitrary-order multiset — so ANY complete snapshot hands
-    off exactly, and a UNION of (partial or complete) snapshots does too
-    (connectivity of a union of same-connectivity sets is unchanged).
-    That makes speculation free of correctness risk: partial buffers from
-    abandoned fetches are simply kept and fed to the union-find alongside
-    one complete snapshot; the only cost of a wrong guess is bytes.
-
-    Policy: once live <= SHEEP_OVERLAP_SPEC_FACTOR * n (default 8) and
-    the snapshot is at least SHEEP_OVERLAP_MIN_MB (default 4), start
-    streaming it while the chunk loop keeps reducing.  At each later
-    chunk: if the stream finished, stop the loop (the handoff set is
-    already on the host — remaining device rounds would be pure waste);
-    if the bytes still in flight exceed 1.25x a fresh fetch of the
-    now-smaller snapshot, abandon (keeping the partial) and restart on
-    the smaller one.  At loop end, either wait out the stream (when its
-    remainder is cheaper than a fresh final fetch) or abandon and fetch
-    the final set directly.  On a fast link the stream wins early and
-    skips device rounds; on a slow link the rule degrades to today's
-    serial fetch, minus nothing.
-    """
-
-    MARGIN = 1.25
-
-    def __init__(self, n: int):
-        self.n = n
-        self.bpl = 6 if pack_handoff(n) else 8
-        self.spec_live = int(os.environ.get(
-            "SHEEP_OVERLAP_SPEC_FACTOR", "8")) * n
-        self.slice_links = int(os.environ.get(
-            "SHEEP_OVERLAP_SLICE", str(1 << 18)))
-        self.min_bytes = int(float(os.environ.get(
-            "SHEEP_OVERLAP_MIN_MB", "4")) * (1 << 20))
-        self.active: _StreamFetcher | None = None
-        self.kept: list[tuple[np.ndarray, np.ndarray]] = []
-        self.stats: dict = {"overlap": True, "spec_starts": 0,
-                            "spec_restarts": 0, "spec_wasted_mb": 0.0,
-                            "spec_stopped_loop": False,
-                            "spec_mode": "never_started"}
-
-    @staticmethod
-    def maybe(n: int) -> "_SpecHandoff | None":
-        from ..core.forest import native_or_none
-        if not _overlap_enabled() or native_or_none("auto") is None:
-            return None
-        return _SpecHandoff(n)
-
-    def _start(self, lo, hi, live: int) -> None:
-        self.active = _StreamFetcher(lo, hi, self.n, live,
-                                     self.slice_links)
-        self.stats["spec_starts"] += 1
-        self.stats.setdefault("spec_start_live", live)
-
-    def _abandon(self) -> None:
-        f = self.active
-        self.active = None
-        if f is None:
-            return
-        f.abort()
-        if f.error is not None:
-            raise f.error
-        self.stats["spec_wasted_mb"] = round(
-            self.stats["spec_wasted_mb"] + f.fetched_bytes() / (1 << 20), 2)
-        if f.done_slices:
-            self.kept.append(f.collect())
-
-    def on_chunk(self, lo, hi, live) -> bool:
-        """reduce_links_hosted ``watch`` hook: True stops the loop."""
-        live = int(live)
-        if self.active is not None:
-            if self.active.error is not None:
-                raise self.active.error
-            if self.active.finished():
-                self.stats["spec_stopped_loop"] = True
-                return True
-            if self.active.remaining_bytes() > \
-                    live * self.bpl * self.MARGIN:
-                self.stats["spec_restarts"] += 1
-                self._abandon()
-                # restarts honor the same min_bytes floor as first
-                # starts (ADVICE r05): a late-loop restart on a tiny
-                # snapshot pays a pack dispatch and possibly a fresh
-                # slice-program compile to save a fetch that costs less
-                # than either
-                if live * self.bpl >= self.min_bytes:
-                    self._start(lo, hi, live)
-            return False
-        if live <= self.spec_live and live * self.bpl >= self.min_bytes:
-            self._start(lo, hi, live)
-        return False
-
-    def abort_all(self) -> None:
-        """Converged without a handoff: nothing to collect."""
-        if self.active is not None:
-            self.active.abort()
-            self.active = None
-        self.kept = []
-
-    def complete(self, lo, hi, live: int) -> tuple[np.ndarray, np.ndarray]:
-        """Produce the host handoff link set at loop end: one complete
-        snapshot (streamed or freshly fetched) plus any kept partials."""
-        live = int(live)
-        mode = "plain"
-        lo_h = hi_h = None
-        f = self.active
-        if f is not None and f.error is not None:
-            raise f.error
-        if f is not None and not f.failed:
-            if f.finished():
-                mode = "spec_complete"
-            elif f.remaining_bytes() <= live * self.bpl:
-                mode = "spec_wait"
-                # generous watchdog (remaining bytes at 0.5MB/s plus
-                # grace): a wedged stream must not hold the build; it
-                # falls back to the serial fetch, recorded as
-                # spec_wait_timeout
-                f.join(timeout=f.remaining_bytes() / 5e5 + 120.0)
-                if f.error is not None:
-                    raise f.error
-            else:
-                self._abandon()
-                f = None
-                mode = "restart_final"
-            if f is not None and not f.failed:
-                lo_h, hi_h = f.collect()
-                self.active = None
-        if lo_h is None:
-            # never started / timed out / abandoned-at-end: fetch the final
-            # reduced set the serial way (production fetch policy)
-            lo_h, hi_h, _ = fetch_links_host(lo, hi, live, self.n)
-            if mode == "spec_wait":
-                # the watchdog fired mid-wait: record it honestly (the
-                # A/B decision reader must distinguish a wedged stream
-                # from one that never started) and count its bytes
-                mode = "spec_wait_timeout"
-                if f is not None:
-                    self.stats["spec_wasted_mb"] = round(
-                        self.stats["spec_wasted_mb"]
-                        + f.fetched_bytes() / (1 << 20), 2)
-            elif mode not in ("restart_final",):
-                mode = "plain"
-        if self.kept:
-            klo, khi = zip(*self.kept)
-            lo_h = np.concatenate([lo_h, *klo])
-            hi_h = np.concatenate([hi_h, *khi])
-            self.kept = []
-        keep = lo_h < self.n
-        self.stats["spec_mode"] = mode
-        return np.ascontiguousarray(lo_h[keep]), \
-            np.ascontiguousarray(hi_h[keep])
-
-
-def reduce_and_fetch_links(lo, hi, n: int, stop_live: int,
-                           handoff_input: bool = False, perf=None):
-    """THE production reduce+handoff middle of the hybrid, shared with
-    scripts/hybrid_profile so the profiler can never drift from what the
-    hybrid ships: chunk rounds to ``stop_live`` with the speculative
-    overlapped fetch on accelerators (:class:`_SpecHandoff`; serial
-    fetch elsewhere).
-
-    Returns (kind, a, b, live, rounds) where kind is "device" (converged
-    before the threshold: a/b are device link arrays for
-    parent_from_links) or "host" (a/b are host int arrays of the fetched
-    handoff links, already lo<n-filtered).  ``perf``, when a dict, gains
-    loop_s / fetch_tail_s (the serialized equivalents of the old
-    profiler's reduce / d2h phases) and the speculation counters.
-    """
-    from .forest import reduce_links_hosted
-
-    spec = _SpecHandoff.maybe(n)
-    t0 = time.perf_counter()
-    with obs.span("reduce.loop", stop_live=stop_live):
-        lo, hi, live, rounds, converged = reduce_links_hosted(
-            lo, hi, n, stop_live=stop_live, handoff_input=handoff_input,
-            watch=spec.on_chunk if spec is not None else None)
-    t1 = time.perf_counter()
-    if converged:
-        if spec is not None:
-            spec.abort_all()
-        if perf is not None:
-            perf["loop_s"] = round(t1 - t0, 4)
-            perf["fetch_tail_s"] = 0.0
-            if spec is not None:
-                perf.update(spec.stats)
-        return "device", lo, hi, int(live), rounds
-    with obs.span("handoff.fetch", live=int(live),
-                  spec=spec is not None):
-        if spec is not None:
-            lo_h, hi_h = spec.complete(lo, hi, int(live))
-        else:
-            lo_h, hi_h, _ = fetch_links_host(lo, hi, int(live), n)
-    if perf is not None:
-        perf["loop_s"] = round(t1 - t0, 4)
-        perf["fetch_tail_s"] = round(time.perf_counter() - t1, 4)
-        # the ACTUAL handed-off link count (ADVICE r05): with
-        # speculation, a/b can be a strictly larger early snapshot plus
-        # kept partials, so `live` alone misreads the handoff volume
-        perf["handoff_links"] = int(len(lo_h))
-        perf["packed_handoff"] = pack_handoff(n)
-        if spec is not None:
-            perf.update(spec.stats)
-    return "host", lo_h, hi_h, int(live), rounds
-
-
 def reduce_and_finish_native(lo, hi, n: int, stop_live: int,
                              handoff_input: bool = False, pst_h=None,
                              accumulate_pst_ok: bool = False, perf=None):
     """THE production reduce + handoff + native-tail of the hybrid,
-    shared with ops.stream's final fold and scripts/hybrid_profile so
-    none of them can drift from what the hybrid ships.
+    shared with ops.stream's final fold so the two cannot drift.
 
-    With the streaming windowed handoff enabled (the default —
-    :func:`stream_handoff_enabled`) the tail is :func:`_stream_tail`:
-    W ascending hi-quantile windows, each folded through the resumable
+    Chunk rounds to ``stop_live``, then :func:`_stream_tail`: W
+    ascending hi-quantile windows, each folded through the resumable
     native union-find the moment it lands, fold k overlapping fetch k+1,
-    the full link table never host-resident.  Disabled,
-    the tail is the legacy serial path (reduce_and_fetch_links +
-    finish_native_host) including the speculative overlapped snapshot on
-    accelerators.
+    the full link table never host-resident.
 
     Returns ("device", lo, hi, live, rounds) when the reduce loop
     converged before the handoff threshold (the links already form the
@@ -1089,42 +739,20 @@ def reduce_and_finish_native(lo, hi, n: int, stop_live: int,
     immediate-handoff exit (zero rounds — any chunk round rewrites the
     multiset, after which only the prep-time pst is right).
 
-    ``perf`` gains loop_s and fetch_tail_s — fetch_tail_s is now the
+    ``perf`` gains loop_s and fetch_tail_s — fetch_tail_s is the
     whole tail wall (fetch + fold minus their overlap) — plus the
     per-window breakdown (fetch_windows, window_fetch_s / window_fold_s,
     overlap_s / overlap_frac, stream_mode) and handoff_links.
     """
     from .forest import reduce_links_hosted
 
-    if not stream_handoff_enabled():
-        kind, a, b, live, rounds = reduce_and_fetch_links(
-            lo, hi, n, stop_live=stop_live, handoff_input=handoff_input,
-            perf=perf)
-        if kind == "device":
-            return "device", a, b, live, rounds
-        t0 = time.perf_counter()
-        with obs.span("handoff.fold", links=len(a)):
-            parent, pst = finish_native_host(a, b, n, pst_h)
-        if perf is not None:
-            # serial tail accounting mirrors the streamed one: the fold
-            # is part of the handoff bill either way
-            perf["fold_s"] = round(time.perf_counter() - t0, 4)
-            perf["fetch_tail_s"] = round(
-                perf.get("fetch_tail_s", 0.0) + perf["fold_s"], 4)
-            perf["fetch_windows"] = 0
-        return "forest", parent, pst, live, rounds
     t0 = time.perf_counter()
-    # handoff_sort=False: the streaming tail feeds the cache-blocked
-    # kernel (raw order reads faster than the sort costs) or sorts by hi
-    # itself for the window slices — either way _sorted_once is waste
     with obs.span("reduce.loop", stop_live=stop_live):
         lo, hi, live, rounds, converged = reduce_links_hosted(
-            lo, hi, n, stop_live=stop_live, handoff_input=handoff_input,
-            handoff_sort=False)
+            lo, hi, n, stop_live=stop_live, handoff_input=handoff_input)
     t1 = time.perf_counter()
     if perf is not None:
         perf["loop_s"] = round(t1 - t0, 4)
-        perf["overlap"] = False  # the spec-snapshot stream is superseded
     if converged:
         if perf is not None:
             perf["fetch_tail_s"] = 0.0
@@ -1135,40 +763,3 @@ def reduce_and_finish_native(lo, hi, n: int, stop_live: int,
     if perf is not None:
         perf["fetch_tail_s"] = round(time.perf_counter() - t1, 4)
     return "forest", parent, pst, int(live), rounds
-
-
-def finish_native_host(lo_h: np.ndarray, hi_h: np.ndarray, n: int, pst_h):
-    """Exact union-find tail on HOST link arrays: returns (parent, pst)
-    uint32 [n].  pst_h may be a zero-arg callable resolved here — after
-    the link fetch — so a caller's prefetch thread keeps overlapping it.
-    Dtype conversion goes through the no-copy reinterpret (_as_u32): the
-    old unconditional .astype(np.uint32) duplicated multi-hundred-MB
-    arrays that were already uint32-exact int32."""
-    if callable(pst_h):
-        pst_h = pst_h()
-    from ..core.forest import native_or_none
-    native = native_or_none("auto")
-    if native is not None:
-        return native.build_forest_links(
-            _as_u32(lo_h), _as_u32(hi_h), n, pst_h)
-    from ..core.forest import build_forest_links
-    forest = build_forest_links(np.asarray(lo_h, dtype=np.int64),
-                                np.asarray(hi_h, dtype=np.int64), n,
-                                pst=pst_h, impl="python")
-    return forest.parent, forest.pst_weight
-
-
-def handoff_finish_native(lo, hi, live: int, n: int, pst_h):
-    """Fetch a reduced link set and finish with the exact sequential
-    union-find (the hybrid tail): returns (parent, pst) uint32 [n].
-
-    lo/hi: device int32 arrays whose first ``live`` slots contain the live
-    links (plus possibly a few dead sentinels — filtered here); pst_h: the
-    accumulated pst counts, host-side — an array, or a zero-arg callable
-    resolved only after the link fetch (lets a caller's prefetch thread
-    overlap that fetch).  The fetch is 64K-granular (each distinct slice
-    length is a fresh XLA program) and 6-byte-packed on accelerators
-    (SHEEP_PACK_HANDOFF overrides; needs n < 2^24).
-    """
-    lo_h, hi_h, _ = fetch_links_host(lo, hi, live, n)
-    return finish_native_host(lo_h, hi_h, n, pst_h)

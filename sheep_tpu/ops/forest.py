@@ -719,19 +719,6 @@ def _depth_tier(size: int, pad: int, in_schedule: bool, levels: int,
     return min(levels + 6, cap)
 
 
-@jax.jit
-def _sorted_once(lo: jnp.ndarray, hi: jnp.ndarray):
-    """One plain lexicographic sort as its own cached XLA program, for
-    the immediate-handoff path.  Measured at 2^22 (cpu backend, full-E
-    handoff): raw order 11.1s total, sort-only 9.2s (the native UF reads
-    a sorted stream 3x faster once its parent array outgrows cache),
-    sort+rewrite 23.7s (the rewrite scrambles the order — chain links
-    land at scattered hub positions, worse than raw for the UF), and
-    sort+rewrite+re-sort 10.5s (the dedupe doesn't pay for the second
-    sort).  Plain sort wins."""
-    return sort_links(lo, hi)
-
-
 def _live_links_np(lo, hi, n: int):
     """Host copies of the live links (lo < n) — the checkpointable state
     at a chunk boundary (runtime/snapshot.py's soundness argument)."""
@@ -745,8 +732,7 @@ def reduce_links_hosted(lo, hi, n: int, stop_live: int = 0,
                         levels: int = 10, jrounds: int = 8,
                         first_levels: int = 4,
                         handoff_input: bool = False,
-                        handoff_sort: bool = True,
-                        watch=None, runtime=None):
+                        runtime=None):
     """Run chunk rounds until convergence (or until live <= stop_live),
     compacting between dispatches.
 
@@ -754,16 +740,6 @@ def reduce_links_hosted(lo, hi, n: int, stop_live: int = 0,
     (lo, hi, live, rounds, converged) with lo/hi on device, all remaining
     live links in the first ``live`` slots' prefix region (plus possibly a
     few dead ones — callers must still mask lo < n).
-
-    ``watch`` — optional hook called after each sorted chunk's stats land
-    with the snapshot ``(lo, hi, live)``: immutable device arrays with the
-    live-prefix guarantee, in the ORIGINAL vertex space only (the hook is
-    skipped once a vertex remap is active).  Returning True stops the loop
-    right there (returned converged=False).  This is how the hybrid's
-    overlapped speculative handoff (ops.build) fetches an early snapshot
-    concurrently with later chunks: every chunk output has the same
-    threshold connectivity, so any complete snapshot — or a union of
-    snapshots — hands off soundly.
 
     A sort-free jump-only opener round runs first, then chunks follow
     ``_CHUNK_SCHEDULE`` and repeat ``jrounds``; lifting depth escalates
@@ -811,24 +787,13 @@ def reduce_links_hosted(lo, hi, n: int, stop_live: int = 0,
         # carry contract needs the dedupe rounds): the opener + a sorted
         # chunk retire ~nothing before the live check stops the loop
         # anyway (measured 10.3s of a 13.8s CPU hybrid at 2^22 with
-        # factor 8, where stop_live == E).  What the handoff stream
-        # needs depends on whether the union-find's parent array still
-        # fits in cache: below n ~ 2^21 (UF state < ~16MB) raw R-MAT
-        # order chases fine (0.28s at 2^20) and any device work is a
-        # loss; above it, raw order thrashes (8.2s vs 2.9s at 2^22) and
-        # one plain sort on the POW2-PADDED arrays (bounded compile
-        # variants, sentinels sort last) pays for itself in the native
-        # tail (see _sorted_once for the rejected rewrite variants).
+        # factor 8, where stop_live == E).  The links go back in raw
+        # order: the cache-blocked kernel's quantile bucketing reads raw
+        # order faster than a device sort costs (1.54s raw fold vs 3.65s
+        # sort + 0.98s sorted fold at 2^22 on the 1-core host), and the
+        # windowed tail sorts by hi itself where it streams windows.
         # The returned count stays the sentinel-inclusive upper bound;
-        # callers' lo < n filter drops dead slots.  ``handoff_sort``
-        # False skips the sort: the round-6 cache-blocked kernel's
-        # quantile bucketing reads RAW order faster than the sort costs
-        # (1.54s raw fold vs 3.65s sort + 0.98s sorted fold at 2^22 on
-        # the 1-core host), and the streaming windowed tail orders its
-        # windows itself — the pre-blocked measurement above predates
-        # both.
-        if n >= (1 << 21) and handoff_sort:
-            lo, hi = _sorted_once(lo, hi)
+        # callers' lo < n filter drops dead slots.
         return lo, hi, e, 0, False
     rounds = 0
     chunk_i = 0
@@ -882,8 +847,6 @@ def reduce_links_hosted(lo, hi, n: int, stop_live: int = 0,
         if stop_live and live_i <= stop_live:
             rlo, rhi = _restore(alo, ahi)
             return (rlo, rhi, live_i, rounds_ret, False), live_i, moved_i
-        if watch is not None and back is None and watch(alo, ahi, live_i):
-            return (alo, ahi, live_i, rounds_ret, False), live_i, moved_i
         return None, live_i, moved_i
 
     def _compact(alo, ahi, live_i):

@@ -172,9 +172,7 @@ def build_graph_streaming_hosted(blocks, n: int, pos: np.ndarray,
     # the last few thousand links (SCALE_r03: 781 total rounds).
     from .build import (default_handoff_factor, handoff_input_ok,
                         reduce_and_finish_native)
-    # same production reduce+tail as the hybrid — the streaming windowed
-    # handoff when enabled, the serial fetch (with the speculative
-    # snapshot stream on accelerators) otherwise.  pst here is the
+    # same production reduce+tail as the hybrid.  pst here is the
     # accumulated per-block count, NOT recoverable from the carry links
     # (they were rewritten by the mid-stream folds), so the fold always
     # receives it precomputed.

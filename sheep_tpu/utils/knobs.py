@@ -130,8 +130,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
     _K("SHEEP_ICI_GBPS", "float", "",
        "mesh", "assumed per-link ICI bandwidth for bench modeling"),
     # -- streaming handoff / hybrid tail (ISSUE 8) -------------------------
-    _K("SHEEP_STREAM_HANDOFF", "flag", "1",
-       "stream", "streaming windowed handoff for the hybrid tail"),
     _K("SHEEP_HANDOFF_WINDOWS", "int", "cpu 1 / accel 4",
        "stream", "hi-quantile window count W of the streamed handoff"),
     _K("SHEEP_HANDOFF_FACTOR", "int", "8",
@@ -144,18 +142,10 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "stream", "host-native counting-sort degree sequence for the "
        "streaming hybrid"),
     _K("SHEEP_PACK_HANDOFF", "flag", "0",
-       "stream", "pack (h<<32|lo) handoff records across serial+stream "
-       "fetches"),
-    _K("SHEEP_OVERLAP_HANDOFF", "flag", "0",
-       "stream", "legacy speculative-snapshot overlap arm (round 4/5 "
-       "A/B)"),
+       "stream", "pack the windowed handoff's fetch slices into 6-byte "
+       "(h<<32|lo) records"),
     _K("SHEEP_OVERLAP_SLICE", "int", "262144",
-       "stream", "links per async fetch slice of the overlap path"),
-    _K("SHEEP_OVERLAP_MIN_MB", "int", "4",
-       "stream", "minimum fetch size worth overlapping"),
-    _K("SHEEP_OVERLAP_SPEC_FACTOR", "int", "8",
-       "stream", "speculative-snapshot size factor of the legacy "
-       "overlap arm"),
+       "stream", "links per fetch slice of the windowed handoff"),
     # -- out-of-core + distributed ext (ISSUES 9/13) -----------------------
     _K("SHEEP_EXT_BLOCK", "size", "524288 records",
        "extmem", "ext rung block size in edge records; pinning it is "
@@ -399,8 +389,6 @@ KNOBS: dict[str, Knob] = {k.name: k for k in [
        "bench", "per-size forced-thread A/B arm in bench.py"),
     _K("SHEEP_MESHBENCH_REPS", "int", "3",
        "bench", "mesh_bench repetitions"),
-    _K("SHEEP_PROFILE_REPS", "int", "3",
-       "bench", "hybrid_profile repetitions"),
     _K("SHEEP_SCALE_BLOCK", "size", "",
        "bench", "scale_run block size override"),
     _K("SHEEP_SCALE_STREAM", "flag", "0",

@@ -1,8 +1,8 @@
 """Pipelined chunk dispatch (SHEEP_PIPELINE_CHUNKS, round 5): the host
 loop keeps the next chunk in flight while the previous chunk's stats
 resolve, compacting one chunk late.  Must be bit-identical to the
-classic loop through every exit path (convergence, stop_live, watch
-early-stop, vremap drain) — the accelerator default is ON, so the CPU
+classic loop through every exit path (convergence, stop_live, vremap
+drain) — the accelerator default is ON, so the CPU
 tests force the gate."""
 
 from __future__ import annotations
@@ -41,8 +41,8 @@ def test_pipelined_fixpoint_matches_classic(monkeypatch, trial):
 def test_pipelined_stop_live_links_rebuild_oracle(monkeypatch, factor):
     """Early stop one chunk late still returns a connectivity-complete
     link set: rebuilding the forest from it matches the oracle."""
+    from sheep_tpu.core.forest import build_forest_links
     from sheep_tpu.ops.forest import reduce_links_hosted
-    from sheep_tpu.ops.build import finish_native_host
 
     rng = np.random.default_rng(4300 + factor)
     tail, head = random_multigraph(rng, n_max=400, e_max=6000)
@@ -57,20 +57,22 @@ def test_pipelined_stop_live_links_rebuild_oracle(monkeypatch, factor):
     lo_h = np.asarray(lo2)
     hi_h = np.asarray(hi2)
     keep = lo_h < n
-    parent, pst_out = finish_native_host(
-        lo_h[keep], hi_h[keep], n, np.asarray(pst, np.uint32)[:n])
-    np.testing.assert_array_equal(parent[:m], want.parent)
-    np.testing.assert_array_equal(pst_out[:m], want.pst_weight)
+    forest = build_forest_links(
+        lo_h[keep].astype(np.uint32), hi_h[keep].astype(np.uint32), n,
+        pst=np.asarray(pst, np.uint32)[:n])
+    np.testing.assert_array_equal(forest.parent[:m], want.parent)
+    np.testing.assert_array_equal(forest.pst_weight[:m], want.pst_weight)
 
 
 def test_pipelined_hybrid_with_overlap(monkeypatch):
-    """Both round-5 mechanisms forced together on cpu: pipelined
-    dispatch + speculative overlapped handoff, end to end."""
+    """Pipelined dispatch and the accelerator's device window queue
+    forced together on cpu, end to end: reduce rounds (factor 2), then
+    the hi-sorted table streams in windows into the resumable fold."""
     from sheep_tpu.ops import build_graph_hybrid
 
     monkeypatch.setenv("SHEEP_PIPELINE_CHUNKS", "1")
-    monkeypatch.setenv("SHEEP_OVERLAP_HANDOFF", "1")
-    monkeypatch.setenv("SHEEP_OVERLAP_MIN_MB", "0.0001")
+    monkeypatch.setenv("SHEEP_STREAM_DEVICE_WINDOWS", "1")
+    monkeypatch.setenv("SHEEP_HANDOFF_WINDOWS", "4")
     monkeypatch.setenv("SHEEP_OVERLAP_SLICE", "4096")
     from sheep_tpu.utils import rmat_edges
     tail, head = rmat_edges(13, 8 << 13, seed=9)

@@ -18,7 +18,7 @@ pytestmark = pytest.mark.skipif(not native.available(),
 #: prep and reduce rounds down to 3n, then the 6-byte packed, four-window
 #: device stream into the resumable fold
 CHIP_POLICY = {"SHEEP_HANDOFF_FACTOR": "3", "SHEEP_HANDOFF_WINDOWS": "4",
-               "SHEEP_STREAM_DEVICE_WINDOWS": "1", "SHEEP_STREAM_HANDOFF": "1",
+               "SHEEP_STREAM_DEVICE_WINDOWS": "1",
                "SHEEP_STREAM_HOST_SEQ": "0", "SHEEP_PACK_HANDOFF": "1",
                "SHEEP_PIPELINE_CHUNKS": "1", "SHEEP_OVERLAP_SLICE": "1024"}
 
